@@ -34,6 +34,8 @@ import sys
 
 NDEV = 8
 if __name__ == "__main__":
+    # A CPU rehearsal by design: pin the platform so it never takes a chip.
+    os.environ["JAX_PLATFORMS"] = "cpu"
     os.environ["XLA_FLAGS"] = (
         f"--xla_force_host_platform_device_count={NDEV} "
         + os.environ.get("XLA_FLAGS", "")
@@ -50,7 +52,7 @@ import numpy as np   # noqa: E402
 
 from repro.core import V5E, distributed as dist  # noqa: E402
 from repro.launch.hlo_analysis import analyze_hlo_text  # noqa: E402
-from repro.launch.mesh import make_mesh_compat  # noqa: E402
+from repro.launch.mesh import make_mesh  # noqa: E402
 from repro.obs.ledger import GemmLedger, reset_ledger, set_ledger  # noqa: E402
 from repro.quant import quantize  # noqa: E402
 from benchmarks.common import time_call  # noqa: E402
@@ -73,7 +75,7 @@ INT8_RIDE_GATE = 0.6
 
 
 def _mesh():
-    return make_mesh_compat((DP, TP), ("data", "model"))
+    return make_mesh((DP, TP), ("data", "model"))
 
 
 def _planned(schedule, itemsize, dtype, dtype_b=None, dtype_a=None):

@@ -176,8 +176,6 @@ def run(records=None):
 
 def _xla_bytes(compiled) -> float:
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):  # jax 0.4.x: one dict per program
-        ca = ca[0] if ca else {}
     return float(ca.get("bytes accessed", 0.0))
 
 

@@ -23,14 +23,16 @@ N = 16384
 _SUB = r"""
 import os, sys, json
 ndev = int(sys.argv[1])
+# A CPU rehearsal by design: pin the platform so it never takes a chip.
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={ndev}"
 sys.path.insert(0, sys.argv[2])
 import jax, jax.numpy as jnp
 from repro.core import dist_matmul
 from repro.launch import hlo_analysis as H
 
-from repro.launch.mesh import make_mesh_compat
-mesh = make_mesh_compat((1, ndev), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((1, ndev), ("data", "model"))
 N = int(sys.argv[3])
 
 def f(a, b):

@@ -21,8 +21,8 @@ from repro.launch import hlo_analysis as H
 
 
 def main():
-    from repro.launch.mesh import make_mesh_compat
-    mesh = make_mesh_compat((2, 4), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((2, 4), ("data", "model"))
     rng = np.random.RandomState(0)
     a = jnp.asarray(rng.randn(256, 512), jnp.float32)
     b = jnp.asarray(rng.randn(512, 384), jnp.float32)

@@ -23,8 +23,9 @@ the MXU's 2x int8 compute rate on top of the byte win
 exactly one request), one recoverable kernel failure (re-dispatched on
 the XLA oracle, ``gemm.fallback_total``), one NaN decode step (walks the
 quant degradation ladder, ``serve.degraded_total``), and one slow decode
-step.  Statuses print per request; pair with ``--metrics`` to see the
-fault counters (see docs/ROBUSTNESS.md).
+step.  It turns the kernel->XLA re-dispatch on, which is off by default.
+Statuses print per request; pair with ``--metrics`` to see the fault
+counters (see docs/ROBUSTNESS.md).
 
 ``--trace trace.jsonl`` writes Chrome-trace-event spans (warmup,
 calibration, per-request prefill/decode) — load the file in Perfetto or
@@ -40,6 +41,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_reduced
+from repro.core import set_gemm_fallback
 from repro.models import common as cm
 from repro.models import model as M
 from repro.obs import enable_tracing, flush
@@ -79,6 +81,8 @@ def main(argv=None):
         print(f"# tracing to {enable_tracing(args.trace)}")
     if args.metrics:
         get_ledger().enable()
+    if args.chaos:
+        set_gemm_fallback(True)
 
     for arch in args.archs:
         cfg = get_reduced(arch)

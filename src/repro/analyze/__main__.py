@@ -58,12 +58,13 @@ def report(argv: Optional[Sequence[str]] = None) -> int:
     from repro.analyze.validate import planned_tile_bytes, \
         validate_program
     from repro.configs import get_config, list_archs
+    from repro.core.io_model import VMEM_BUDGET_FRACTION
     from repro.tuning import get_registry
 
     registry = get_registry()
     hw = registry.hw
     archs = args.arch or list_archs()
-    budget = int(hw.vmem_bytes * 0.75)
+    budget = int(hw.vmem_bytes * VMEM_BUDGET_FRACTION)
     n_diags = 0
     print(f"# static plan report — hw={hw.name} "
           f"(VMEM budget {budget} B)")

@@ -144,7 +144,7 @@ def _repro_subpackage(path: pathlib.Path) -> Optional[str]:
 
 
 def _assert_exempt(path: pathlib.Path) -> bool:
-    """RPR003 skips internal tooling modules (``_stubs/``, ``_x.py``)."""
+    """RPR003 skips internal tooling modules (``_x/``, ``_x.py``)."""
     return any(p.startswith("_") and p != "__init__.py"
                for p in _path_parts(path))
 

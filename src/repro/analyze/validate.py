@@ -23,12 +23,8 @@ import jax.numpy as jnp
 
 from repro.analyze.diagnostics import Diagnostic, error, warning
 from repro.core.hardware import TARGETS, TpuTarget, V5E
-from repro.core.io_model import TileConfig, tile_vmem_bytes
-
-# The fraction of VMEM the tile solve budgets against — must track
-# tuning/space.py's default or the verifier would reject what the solver
-# planned (or bless what it refused).
-DEFAULT_VMEM_FRACTION = 0.75
+from repro.core.io_model import (VMEM_BUDGET_FRACTION, TileConfig,
+                                 tile_vmem_bytes)
 
 _VALID_ORDERS = ("k_inner", "k_outer")
 _ATTN_ORDER = "attn"
@@ -38,14 +34,10 @@ _SHORT_ITEMSIZE = {"bf16": 2, "f16": 2, "f32": 4, "f64": 8, "int8": 1}
 
 
 def _target_by_name(name: str) -> Optional[TpuTarget]:
-    """Resolve a cache key's leading field: TARGETS is keyed by short
-    alias ('v5e') but the registry mints keys with ``hw.name``
-    ('tpu-v5e'), so accept either spelling."""
-    hit = TARGETS.get(name)
-    if hit is not None:
-        return hit
+    """Resolve a cache key's leading field: the registry mints keys with
+    ``hw.name`` ('tpu-v5e'); the short spelling ('v5e') is accepted too."""
     for hw in TARGETS.values():
-        if hw.name == name:
+        if name in (hw.name, hw.name.removeprefix("tpu-")):
             return hw
     return None
 
@@ -105,7 +97,7 @@ def validate_program(tag: str,
                      semiring: str = "plus_times",
                      scale_block: int = 0,
                      act_block: int = 0,
-                     vmem_fraction: float = DEFAULT_VMEM_FRACTION
+                     vmem_fraction: float = VMEM_BUDGET_FRACTION
                      ) -> List[Diagnostic]:
     """Verify one resolved GEMM program against its hard constraints.
 

@@ -1,7 +1,8 @@
 """Core: the paper's contribution — model-driven communication-avoiding
 matrix multiplication — as a composable JAX module."""
 
-from repro.core.hardware import TpuTarget, V5E, V5P, get_target
+from repro.core.hardware import (TpuTarget, V5E, V5P, target_for_device,
+                                  target_for_kind)
 from repro.core.io_model import (
     TileConfig,
     arithmetic_intensity_ops_per_byte,
@@ -35,7 +36,7 @@ from repro.core.distributed import (
 )
 
 __all__ = [
-    "TpuTarget", "V5E", "V5P", "get_target",
+    "TpuTarget", "V5E", "V5P", "target_for_device", "target_for_kind",
     "TileConfig", "computational_intensity", "arithmetic_intensity_ops_per_byte",
     "io_volume_elements", "io_volume_bytes", "io_lower_bound_elements",
     "io_volume_elements_program", "two_pass_glu_q_elements",

@@ -1,5 +1,5 @@
 """Self-test for the distributed GEMM schedules, run in a subprocess with
-forced host devices (so the main test session keeps 1 device).
+forced CPU host devices (so the main test session keeps 1 device).
 
 Usage: python -m repro.core._dist_check [ndev]
 Prints "OK <schedule> ..." lines; exits nonzero on mismatch.
@@ -10,6 +10,8 @@ import sys
 
 if __name__ == "__main__":
     ndev = int(sys.argv[1]) if len(sys.argv) > 1 else 8
+    # A CPU rehearsal by design: pin the platform so it never takes a chip.
+    os.environ["JAX_PLATFORMS"] = "cpu"
     os.environ["XLA_FLAGS"] = (
         f"--xla_force_host_platform_device_count={ndev} "
         + os.environ.get("XLA_FLAGS", "")
@@ -20,7 +22,7 @@ import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
 from repro.core import distributed as dist  # noqa: E402
-from repro.launch.mesh import make_mesh_compat  # noqa: E402
+from repro.launch.mesh import make_mesh  # noqa: E402
 
 
 def _check(name, got, want, failures, atol=1e-3, rtol=1e-4):
@@ -39,7 +41,7 @@ def main(ndev: int) -> int:
     m, k, n = 64, 128, 96
 
     # 2D mesh (data=2, model=ndev//2)
-    mesh = make_mesh_compat((2, ndev // 2), ("data", "model"))
+    mesh = make_mesh((2, ndev // 2), ("data", "model"))
     a = jnp.asarray(rng.randn(m, k), jnp.float32)
     b = jnp.asarray(rng.randn(k, n), jnp.float32)
     want = np.asarray(a) @ np.asarray(b)
@@ -49,7 +51,7 @@ def main(ndev: int) -> int:
 
     # 3D mesh (pod=2, data=2, model=ndev//4) — 2.5D schedule
     if ndev >= 8:
-        mesh3 = make_mesh_compat((2, 2, ndev // 4), ("pod", "data", "model"))
+        mesh3 = make_mesh((2, 2, ndev // 4), ("pod", "data", "model"))
         for sched in ("ring", "ring_unpipelined", "summa25d", "allgather"):
             got = dist.dist_matmul(a, b, mesh3, schedule=sched,
                                    pod_axis="pod")

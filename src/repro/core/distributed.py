@@ -71,24 +71,9 @@ def _dist_error(message: str):
 
     return ProgramValidationError([error("DIST004", message)])
 
-# ---------------------------------------------------------------------------
-# jax version compat: shard_map moved from jax.experimental to jax.shard_map
-# (and check_rep was renamed check_vma); jax.lax.pvary only exists where the
-# VMA type system does.  Old jax has no VMA typing, so no-op pvary is exact.
-# ---------------------------------------------------------------------------
-
-if hasattr(jax, "shard_map"):
-    def _shard_map(f, mesh, in_specs, out_specs, check=True):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check)
-else:  # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _legacy_shard_map
-
-    def _shard_map(f, mesh, in_specs, out_specs, check=True):
-        return _legacy_shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_rep=False)
-
-_pvary = getattr(jax.lax, "pvary", lambda x, axes: x)
+def _shard_map(f, mesh, in_specs, out_specs, check=True):
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check)
 
 
 # ---------------------------------------------------------------------------
@@ -581,7 +566,7 @@ def _dist_matmul_impl(a, b, mesh, *, schedule, dp_axis, tp_axis, pod_axis,
             if vary:
                 # The zero carry starts device-invariant; mark it varying
                 # over the manual axes so carry types match (VMA).
-                acc0 = _pvary(acc0, tuple(vary))
+                acc0 = jax.lax.pcast(acc0, tuple(vary), to="varying")
             c_loc = _ring_chain(a_loc, acc0, partial_fn, axis=tp_axis,
                                 g=tp,
                                 pipelined=(schedule != "ring_unpipelined"),

@@ -70,19 +70,20 @@ class gemm_mode:
 # Kernel-failure fallback (the degradation ladder's first rung)
 # ---------------------------------------------------------------------------
 
-_fallback_enabled = True
+_fallback_enabled = False
 _fallback_lock = threading.Lock()
 
 
 def set_gemm_fallback(enabled: bool) -> None:
     """Enable/disable the kernel-failure -> XLA-oracle re-dispatch.
 
-    On (the production default) a Pallas compile/execute failure — or an
-    injected :class:`~repro.runtime.fault.InjectedKernelFailure` — is
-    counted in ``gemm.fallback_total{stage}`` and the same GEMM re-runs
-    on the XLA oracle path with identical semantics.  Off (what the test
-    suite sets, so kernel bugs cannot hide behind the oracle) the failure
-    propagates to the caller.
+    Off (the default) a kernel-path failure propagates to the caller, so
+    a kernel the chip's compiler refuses can never be served silently by
+    the oracle.  On (what the chaos tests and ``examples/serve_lm.py
+    --chaos`` turn on) a Pallas compile/execute failure — or an injected
+    :class:`~repro.runtime.fault.InjectedKernelFailure` — is counted in
+    ``gemm.fallback_total{stage}`` and the same GEMM re-runs on the XLA
+    oracle path with identical semantics.
     """
     global _fallback_enabled
     with _fallback_lock:

@@ -16,21 +16,26 @@ from __future__ import annotations
 import dataclasses
 from typing import Dict, Tuple
 
+import jax
 import jax.numpy as jnp
 
 
 @dataclasses.dataclass(frozen=True)
 class TpuTarget:
-    """Hardware constants for one TPU chip + its interconnect."""
+    """Hardware constants for one TPU chip + its interconnect.
+
+    Defaults are one TPU v5e chip.  Published figures (Google Cloud
+    documentation, "TPU v5e"): 197 TFLOP/s bf16, 393 TOP/s int8, 16 GB
+    of HBM at 819 GB/s.
+    """
 
     name: str = "tpu-v5e"
 
-    # Compute: peak MAC throughput. 197 TFLOP/s bf16 on the MXU;
-    # fp32 runs at ~1/4 bf16 rate on v5e-class MXUs (passes through the
-    # MXU as multiple bf16x? products); int8 at 2x bf16 (394 TOP/s).
+    # Compute: peak MAC throughput on the MXU.  fp32 is not published;
+    # it runs as multiple bf16 passes, modeled here as 1/4 of bf16.
     peak_flops_bf16: float = 197e12
     peak_flops_fp32: float = 197e12 / 4
-    peak_flops_int8: float = 394e12
+    peak_flops_int8: float = 393e12
 
     # Memory tiers.
     vmem_bytes: int = 128 * 1024 * 1024  # fast memory "S" of the paper
@@ -79,7 +84,8 @@ class TpuTarget:
 # Default production target used throughout the repo.
 V5E = TpuTarget()
 
-# A "big core" variant kept for portability experiments (v5p-like).
+# TPU v5p (Google Cloud documentation, "TPU v5p"): 459 TFLOP/s bf16,
+# 918 TOP/s int8, 95 GB of HBM at 2765 GB/s.
 V5P = TpuTarget(
     name="tpu-v5p",
     peak_flops_bf16=459e12,
@@ -92,8 +98,23 @@ V5P = TpuTarget(
     ici_links=6,
 )
 
-TARGETS: Dict[str, TpuTarget] = {"v5e": V5E, "v5p": V5P}
+# The peak table, keyed by the ``device_kind`` JAX reports for the chip.
+TARGETS: Dict[str, TpuTarget] = {"TPU v5 lite": V5E, "TPU v5": V5P}
 
 
-def get_target(name: str = "v5e") -> TpuTarget:
-    return TARGETS[name]
+def target_for_kind(device_kind: str) -> TpuTarget:
+    """The target of a chip by its ``device_kind``; an unknown kind is an
+    error, never a default."""
+    try:
+        return TARGETS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no peak table entry for device kind {device_kind!r} "
+            f"(known: {sorted(TARGETS)})") from None
+
+
+def target_for_device(device=None) -> TpuTarget:
+    """The target of ``device`` (default: the first JAX device)."""
+    if device is None:
+        device = jax.devices()[0]
+    return target_for_kind(device.device_kind)

@@ -217,6 +217,32 @@ def round_up_to(value: int, quantum: int) -> int:
     return ((value + quantum - 1) // quantum) * quantum
 
 
+# The share of VMEM the tile solve plans for (Eq. 9's capacity); the rest
+# is left to what the compiler adds around a kernel.  Every budget check
+# (solver, candidate space, verifier) reads it from here.
+VMEM_BUDGET_FRACTION = 0.75
+
+# Headroom a kernel's scoped-VMEM request adds on top of its planned
+# bytes, for what Mosaic allocates beyond the operand blocks and the
+# accumulators: the (bm, bn) fp32 partial product of one k step, plus a
+# fixed slack for lane-padded vector operands (rms row scales, gains,
+# dequant scales) and prologue temporaries.  Checked against the
+# compiler by tests/test_tpu_compile.py.
+VMEM_LIMIT_SLACK_BYTES = 2 * 1024 * 1024
+
+
+def kernel_vmem_limit_bytes(planned_bytes: int, bm: int, bn: int,
+                            hw: TpuTarget = V5E) -> int:
+    """The scoped-VMEM limit a GEMM kernel asks the compiler for: its
+    planned bytes plus headroom, capped at the chip's VMEM.
+
+    Mosaic's default scoped limit (16 MiB on v5e) refuses most solved
+    tiles, which plan for up to ``VMEM_BUDGET_FRACTION`` of VMEM.
+    """
+    return int(min(hw.vmem_bytes,
+                   planned_bytes + bm * bn * 4 + VMEM_LIMIT_SLACK_BYTES))
+
+
 def memory_utilization(bm: int, bn: int, bk: int, itemsize_in: int,
                        acc_bytes: int, hw: TpuTarget = V5E) -> float:
     """Fig. 3 analog: fraction of fast memory actually used by the tiles."""
@@ -321,7 +347,7 @@ def solve_tile_config(
     dtype_in=jnp.bfloat16,
     dtype_acc=jnp.float32,
     hw: TpuTarget = V5E,
-    vmem_fraction: float = 0.75,
+    vmem_fraction: float = VMEM_BUDGET_FRACTION,
     # Perf iteration #1 (EXPERIMENTS §Perf): 2048 left the capacity
     # constraint slack (44% VMEM util for bf16) and intensity at 1024;
     # letting the Eq. 5 capacity bound bind raises AI ~1.9x.

@@ -60,11 +60,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
+from repro.core import io_model
 from repro.kernels.epilogue import EpilogueSpec, act_fn
 from repro.kernels.program import (GemmProgramSpec, NO_PROLOGUE,
                                    PrologueSpec, PLAIN,
-                                   apply_dact_reference)
+                                   apply_dact_reference, program_cost)
 
 
 def _acc_dtype(dtype) -> jnp.dtype:
@@ -531,15 +531,38 @@ def ca_gemm_program(
                 in_specs.append(
                     pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)))
 
-    out_shape = [jax.ShapeDtypeStruct((m, n), out_dtype)
+    # Inside a shard_map the outputs vary over the mesh axes the operands
+    # vary over (empty outside one).
+    vma = frozenset().union(*(jax.typeof(x).vma for x in operands))
+    out_shape = [jax.ShapeDtypeStruct((m, n), out_dtype, vma=vma)
                  for _ in range(spec.n_out)]
     out_specs = [pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j))
                  for _ in range(spec.n_out)]
     if save_preact:
         for _ in range(nb):
-            out_shape.append(jax.ShapeDtypeStruct((m, n), jnp.float32))
+            out_shape.append(jax.ShapeDtypeStruct((m, n), jnp.float32,
+                                                  vma=vma))
             out_specs.append(pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)))
 
+    # Scoped VMEM: the tile's planned bytes (the solver's Eq. 9 count, at
+    # this call's operand dtypes) plus headroom — Mosaic's default limit
+    # refuses most solved tiles.
+    cost = program_cost(tag)
+    mn_itemsize = max((jnp.dtype(ops[name].dtype).itemsize
+                       for bspec, ops in zip(spec.branches, branch_operands)
+                       for name in ("mul", "residual")
+                       if getattr(bspec, "has_" + name)),
+                      default=jnp.dtype(a.dtype).itemsize)
+    planned = io_model.tile_vmem_bytes(
+        bm, bn, bk, mn_itemsize, acc_bytes=jnp.dtype(acc_t).itemsize,
+        itemsize_out=jnp.dtype(out_dtype).itemsize,
+        epilogue_mn_ops=cost.stream_mn, epilogue_bias=cost.has_bias,
+        itemsize_a=jnp.dtype(a.dtype).itemsize,
+        itemsize_b=jnp.dtype(bs[0].dtype).itemsize, n_b=cost.n_b,
+        n_out=cost.n_out, prologue_mk_ops=cost.prologue_mk,
+        prologue_kn_ops=cost.prologue_kn)
+    if save_preact:
+        planned += nb * bm * bn * 4
     kernel = functools.partial(
         _program_kernel, spec=spec, semiring=semiring, kdim=kdim, bk=bk,
         transpose_a=transpose_a, transpose_b=transpose_b,
@@ -552,9 +575,10 @@ def ca_gemm_program(
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((bm, bn), acc_t) for _ in range(nb)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
+            vmem_limit_bytes=io_model.kernel_vmem_limit_bytes(
+                planned, bm, bn)),
         interpret=interpret,
     )(*operands)
     if len(out) == 1:
@@ -660,7 +684,7 @@ def ca_mmm_k_outer(
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda kk, i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), acc_t),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "parallel", "parallel"),
         ),
         interpret=interpret,

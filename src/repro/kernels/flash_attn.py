@@ -29,8 +29,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 NEG = -1e30
 
 
@@ -160,7 +158,7 @@ def flash_attention_tpu(
             pltpu.VMEM((G * qc,), jnp.float32),
             pltpu.VMEM((G * qc,), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qpos_r, kpos_r, qr, kr, vr)
@@ -186,7 +184,7 @@ def _paged_fa_kernel(tables_ref, lens_ref, ksc_ref, vsc_ref, q_ref,
     dequant scales ride the kv step exactly like per-tile ``dqb``
     b-scales ride a quantized GEMM's k-step — applied to the partial
     scores / partial PV product in VMEM, so the dequantized K/V never
-    exist in HBM.
+    exist in HBM.  Scales and lengths are scalar-prefetch (SMEM) reads.
     """
     bh = pl.program_id(0)
     j = pl.program_id(1)
@@ -199,10 +197,10 @@ def _paged_fa_kernel(tables_ref, lens_ref, ksc_ref, vsc_ref, q_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
 
     q = q_ref[0]                       # (G, D) serve dtype
-    k = k_ref[0, :, 0, :]              # (page, D) int8 payload
-    v = v_ref[0, :, 0, :]              # (page, Dv) int8 payload
-    ksc = ksc_ref[0, 0]                # per-page fp32 scale (this page)
-    vsc = vsc_ref[0, 0]
+    k = k_ref[0, 0]                    # (page, D) int8 payload
+    v = v_ref[0, 0]                    # (page, Dv) int8 payload
+    ksc = ksc_ref[b, j]                # this page's fp32 scales
+    vsc = vsc_ref[b, j]
     # Dequant fused into the score accumulate: the int8 page contracts
     # directly and the page scale folds into the softmax logit scale.
     s = jax.lax.dot_general(
@@ -218,30 +216,29 @@ def _paged_fa_kernel(tables_ref, lens_ref, ksc_ref, vsc_ref, q_ref,
         mask &= kpos > qpos - window
     s = jnp.where(mask, s, NEG)
 
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, s.max(axis=1))
-    p = jnp.exp(s - m_new[:, None])
+    m_prev = m_ref[...]                # (G, 1)
+    m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+    p = jnp.exp(s - m_new)
     p = jnp.where(mask, p, 0.0)
     alpha = jnp.exp(m_prev - m_new)
     # PV on the int8 page, the page's v-scale riding the partial product.
     pv = jax.lax.dot_general(
         p, v.astype(jnp.float32), (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32) * vsc
-    acc_ref[...] = acc_ref[...] * alpha[:, None] + pv
-    l_ref[...] = l_ref[...] * alpha + p.sum(axis=1)
+    acc_ref[...] = acc_ref[...] * alpha + pv
+    l_ref[...] = l_ref[...] * alpha + p.sum(axis=1, keepdims=True)
     m_ref[...] = m_new
 
     @pl.when(j == pl.num_programs(1) - 1)
     def _drain():
-        o_ref[0] = (acc_ref[...]
-                    / jnp.maximum(l_ref[...], 1e-30)[:, None]
+        o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
                     ).astype(o_ref.dtype)
 
 
 def paged_flash_attention_tpu(
     q: jax.Array,                 # (B, H, D) — one decode token per seq
-    k_pages: jax.Array,           # (P, page, Hkv, D) int8
-    v_pages: jax.Array,           # (P, page, Hkv, Dv) int8
+    k_pages: jax.Array,           # (P, Hkv, page, D) int8
+    v_pages: jax.Array,           # (P, Hkv, page, Dv) int8
     k_scale: jax.Array,           # (P,) fp32 per-page scales
     v_scale: jax.Array,           # (P,) fp32
     block_tables: jax.Array,      # (B, NP) int32 page ids; -1 = unmapped
@@ -257,14 +254,17 @@ def paged_flash_attention_tpu(
     (:class:`pltpu.PrefetchScalarGridSpec`): page ids are available
     before the kernel body runs, so the K/V ``index_map`` gathers page
     ``tables[b, j]`` of the pool for kv step ``j`` — the PagedAttention
-    layout under the paper's single-drain kernel structure.  Positions
-    are implicit (token ``t`` of page step ``j`` sits at ``j*page + t``),
-    so ragged lengths, partially-filled tail pages and unmapped table
-    slots all mask through one ``kpos < seq_len`` predicate.  Returns
-    ``(B, H, Dv)`` in ``q.dtype``.
+    layout under the paper's single-drain kernel structure.  Pages are
+    stored head-major, so one grid step streams one kv head's
+    ``(page, D)`` tile: a block whose two minor dims are the array's own,
+    which the TPU lowering accepts for any page size and head dim.
+    Positions are implicit (token ``t`` of page step ``j`` sits at
+    ``j*page + t``), so ragged lengths, partially-filled tail pages and
+    unmapped table slots all mask through one ``kpos < seq_len``
+    predicate.  Returns ``(B, H, Dv)`` in ``q.dtype``.
     """
     B, H, D = q.shape
-    P, page, Hkv, Dv = v_pages.shape
+    P, Hkv, page, Dv = v_pages.shape
     G = H // Hkv
     NP = block_tables.shape[1]
     scale = D ** -0.5 if scale is None else scale
@@ -276,34 +276,32 @@ def paged_flash_attention_tpu(
     ksc = k_scale[tables]              # (B, NP) fp32
     vsc = v_scale[tables]
 
+    def page_map(bh, j, t, lens, ks, vs):
+        return (t[bh // Hkv, j], bh % Hkv, 0, 0)
+
     grid = (B * Hkv, NP)
     kernel = functools.partial(_paged_fa_kernel, page=page, n_kv=Hkv,
                                window=window, scale=scale)
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,     # block table + seq lens
+            # block table, seq lens, k scales, v scales
+            num_scalar_prefetch=4,
             grid=grid,
             in_specs=[
-                pl.BlockSpec((1, 1), lambda bh, j, t, l: (bh // Hkv, j)),
-                pl.BlockSpec((1, 1), lambda bh, j, t, l: (bh // Hkv, j)),
-                pl.BlockSpec((1, G, D), lambda bh, j, t, l: (bh, 0, 0)),
-                pl.BlockSpec((1, page, 1, D),
-                             lambda bh, j, t, l: (t[bh // Hkv, j], 0,
-                                                  bh % Hkv, 0)),
-                pl.BlockSpec((1, page, 1, Dv),
-                             lambda bh, j, t, l: (t[bh // Hkv, j], 0,
-                                                  bh % Hkv, 0)),
+                pl.BlockSpec((1, G, D), lambda bh, j, *_: (bh, 0, 0)),
+                pl.BlockSpec((1, 1, page, D), page_map),
+                pl.BlockSpec((1, 1, page, Dv), page_map),
             ],
-            out_specs=pl.BlockSpec((1, G, Dv), lambda bh, j, t, l: (bh, 0, 0)),
+            out_specs=pl.BlockSpec((1, G, Dv), lambda bh, j, *_: (bh, 0, 0)),
             scratch_shapes=[
                 pltpu.VMEM((G, Dv), jnp.float32),
-                pltpu.VMEM((G,), jnp.float32),
-                pltpu.VMEM((G,), jnp.float32),
+                pltpu.VMEM((G, 1), jnp.float32),
+                pltpu.VMEM((G, 1), jnp.float32),
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B * Hkv, G, Dv), q.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(tables, seq_lens.astype(jnp.int32), ksc, vsc, qr, k_pages, v_pages)

@@ -4,8 +4,8 @@ One *layer-level* cache is the pytree
 
 .. code-block:: python
 
-    {"k":       (P, page, Hkv, D)  int8,   # page pool, K payload
-     "v":       (P, page, Hkv, Dv) int8,
+    {"k":       (P, Hkv, page, D)  int8,   # page pool, K payload
+     "v":       (P, Hkv, page, Dv) int8,
      "k_scale": (P,) float32,              # per-page absmax scales
      "v_scale": (P,) float32,
      "tables":  (B, NP) int32,             # block table; -1 = unmapped
@@ -14,7 +14,9 @@ One *layer-level* cache is the pytree
 The model stacks one of these per layer along a leading axis (exactly
 like the slab caches), sharing the page *ids* across layers: page ``p``
 of layer ``l`` lives at ``k[l, p]``, so one host-side allocation
-(:class:`repro.kvcache.pool.PagePool`) covers the whole depth.
+(:class:`repro.kvcache.pool.PagePool`) covers the whole depth.  Pages are
+head-major: each kv head's ``(page, D)`` tile is contiguous, which is
+the block the decode kernel streams per grid step.
 
 Quantization reuses the :mod:`repro.quant.scales` convention: int8
 symmetric on [-127, 127], fp32 scales.  Prefill bulk-inserts whole pages
@@ -49,8 +51,8 @@ def make_paged_cache(n_pages: int, page_size: int, n_kv: int, dk: int,
                      ) -> Dict[str, jax.Array]:
     """One layer's empty paged cache (see module docstring for layout)."""
     return {
-        "k": jnp.zeros((n_pages, page_size, n_kv, dk), jnp.int8),
-        "v": jnp.zeros((n_pages, page_size, n_kv, dv), jnp.int8),
+        "k": jnp.zeros((n_pages, n_kv, page_size, dk), jnp.int8),
+        "v": jnp.zeros((n_pages, n_kv, page_size, dv), jnp.int8),
         "k_scale": jnp.zeros((n_pages,), jnp.float32),
         "v_scale": jnp.zeros((n_pages,), jnp.float32),
         "tables": jnp.full((batch, max_pages), -1, jnp.int32),
@@ -121,7 +123,7 @@ def paged_prefill_insert(cache: Dict[str, jax.Array], k_new: jax.Array,
     """
     B, L, Hkv, Dk = k_new.shape
     Dv = v_new.shape[-1]
-    page = cache["k"].shape[1]
+    page = cache["k"].shape[2]
     npg = -(-L // page)
     pad = npg * page - L
 
@@ -134,7 +136,8 @@ def paged_prefill_insert(cache: Dict[str, jax.Array], k_new: jax.Array,
         scale = jnp.maximum(amax, _EPS) / _QMAX
         q = jnp.clip(jnp.round(xb / scale[:, :, None, None, None]),
                      -_QMAX, _QMAX).astype(jnp.int8)
-        return q.reshape(B * npg, page, Hkv, d), scale.reshape(B * npg)
+        q = q.transpose(0, 1, 3, 2, 4)                       # head-major
+        return q.reshape(B * npg, Hkv, page, d), scale.reshape(B * npg)
 
     kq, ks = quantize_pages(k_new, Dk)
     vq, vs = quantize_pages(v_new, Dv)
@@ -157,8 +160,8 @@ def _append_token(pool: jax.Array, scales: jax.Array, pid: jax.Array,
     old grid, and exactly 0 for a fresh page whose scale is 0 — stale
     bytes die here).  One page round-trips VMEM; the pool doesn't.
     """
-    page, n_kv, d = pool.shape[1:]
-    old = jax.lax.dynamic_slice(pool, (pid, 0, 0, 0), (1, page, n_kv, d))
+    n_kv, page, d = pool.shape[1:]
+    old = jax.lax.dynamic_slice(pool, (pid, 0, 0, 0), (1, n_kv, page, d))
     old_sc = scales[pid]
     tokf = tok.astype(jnp.float32)
     new_sc = jnp.maximum(old_sc, jnp.maximum(jnp.max(jnp.abs(tokf)),
@@ -168,8 +171,8 @@ def _append_token(pool: jax.Array, scales: jax.Array, pid: jax.Array,
                         -_QMAX, _QMAX).astype(jnp.int8)
     tok_q = jnp.clip(jnp.round(tokf / new_sc), -_QMAX, _QMAX
                      ).astype(jnp.int8)
-    pg = jax.lax.dynamic_update_slice(rescaled, tok_q[None, None],
-                                      (0, slot, 0, 0))
+    pg = jax.lax.dynamic_update_slice(rescaled, tok_q[None, :, None],
+                                      (0, 0, slot, 0))
     pool = jax.lax.dynamic_update_slice(pool, pg, (pid, 0, 0, 0))
     return pool, scales.at[pid].set(new_sc)
 
@@ -183,7 +186,7 @@ def paged_decode_insert(cache: Dict[str, jax.Array], k_new: jax.Array,
     handles page ids — it allocated enough pages up front and the table
     routes the write.
     """
-    page = cache["k"].shape[1]
+    page = cache["k"].shape[2]
     B = cache["tables"].shape[0]
     out = dict(cache)
     for b in range(B):  # B is static and small (the serve batch)
@@ -213,25 +216,25 @@ def gather_kv(cache: Dict[str, jax.Array], dtype=jnp.float32):
     drain stage.
     """
     B, NP = cache["tables"].shape
-    page = cache["k"].shape[1]
+    page = cache["k"].shape[2]
     ids = jnp.maximum(cache["tables"], 0)
     k = (cache["k"][ids].astype(jnp.float32)
          * cache["k_scale"][ids][..., None, None, None])
     v = (cache["v"][ids].astype(jnp.float32)
          * cache["v_scale"][ids][..., None, None, None])
     S = NP * page
-    k = k.reshape(B, S, *k.shape[3:]).astype(dtype)
-    v = v.reshape(B, S, *v.shape[3:]).astype(dtype)
+    # (B, NP, Hkv, page, D) -> token-major (B, NP * page, Hkv, D)
+    k = k.transpose(0, 1, 3, 2, 4).reshape(B, S, k.shape[2],
+                                           k.shape[4]).astype(dtype)
+    v = v.transpose(0, 1, 3, 2, 4).reshape(B, S, v.shape[2],
+                                           v.shape[4]).astype(dtype)
     pos = jnp.arange(S, dtype=jnp.int32)[None, :]
     pos = jnp.where(pos < cache["len"][:, None], pos, -1)
     return k, v, pos
 
 
 def _auto_mode() -> str:
-    try:
-        return "pallas" if jax.default_backend() == "tpu" else "xla"
-    except Exception:  # repro: noqa RPR004 -- pragma: no cover, backend probe never critical
-        return "xla"
+    return "pallas" if jax.default_backend() == "tpu" else "xla"
 
 
 def paged_attention(q: jax.Array, cache: Dict[str, jax.Array], *,
@@ -250,7 +253,7 @@ def paged_attention(q: jax.Array, cache: Dict[str, jax.Array], *,
     recorded in the obs ledger with its planned KV bytes (the
     ``BENCH_attn.json`` accounting).
     """
-    n_pages, page, Hkv, Dv = cache["v"].shape
+    n_pages, Hkv, page, Dv = cache["v"].shape
     NP = cache["tables"].shape[1]
     # KV005 preflight: q must be a single decode step and the cache
     # geometry GQA-compatible; memoized per (shape, page, heads).

@@ -1,4 +1,7 @@
 import os
+# A CPU rehearsal by design: 512 forced host devices stand in for the
+# production mesh, and the platform is pinned so it never takes a chip.
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
@@ -159,8 +162,6 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool,
         compiled = lowered.compile()
 
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):  # jax 0.4.x: one dict per program
-        ca = ca[0] if ca else {}
     ma = compiled.memory_analysis()
     hlo_cost = H.analyze_hlo_text(compiled.as_text())
     art = {

@@ -17,47 +17,21 @@ from typing import Tuple
 
 import jax
 
-# ---------------------------------------------------------------------------
-# jax version compat: AxisType + the AbstractMesh signature changed between
-# 0.4.x and 0.5+.  Everything in this repo builds meshes through these two
-# helpers so the version skew lives here and nowhere else.
-# ---------------------------------------------------------------------------
-
-_AXIS_TYPE = getattr(jax.sharding, "AxisType", None)
-
-
-def _axis_type_kwargs(n_axes: int) -> dict:
-    """{'axis_types': (Auto,)*n} on jax >= 0.5, {} on older jax."""
-    if _AXIS_TYPE is None:
-        return {}
-    return {"axis_types": (_AXIS_TYPE.Auto,) * n_axes}
-
-
-def make_mesh_compat(shape: Tuple[int, ...], axes: Tuple[str, ...]):
-    """``jax.make_mesh`` with Auto axis types where supported."""
-    try:
-        return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
-    except TypeError:
-        return jax.make_mesh(shape, axes)
+def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
+    """``jax.make_mesh`` over the visible devices, every axis Auto."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def abstract_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
-    """``jax.sharding.AbstractMesh`` across both constructor signatures.
-
-    jax >= 0.5: ``AbstractMesh(axis_sizes, axis_names)``;
-    jax 0.4.x:  ``AbstractMesh(((name, size), ...))``.
-    """
-    AM = jax.sharding.AbstractMesh
-    try:
-        return AM(shape, axes)
-    except TypeError:
-        return AM(tuple(zip(axes, shape)))
+    """A device-free mesh of this shape, for sharding rules and specs."""
+    return jax.sharding.AbstractMesh(shape, axes)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(shape: Tuple[int, ...] = None, axes=None):
@@ -66,7 +40,7 @@ def make_host_mesh(shape: Tuple[int, ...] = None, axes=None):
     if shape is None:
         shape = (1, n) if n > 1 else (1, 1)
         axes = ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def batch_axes(mesh) -> Tuple[str, ...]:

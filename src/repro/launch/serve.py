@@ -1,23 +1,30 @@
-"""Serving launcher: batched requests against a (reduced) model.
+"""Serving launcher: a queue of random-prompt requests against one model,
+served one request at a time by :class:`repro.serve.engine.ServeEngine`.
 
   PYTHONPATH=src python -m repro.launch.serve --arch mamba2-370m \
       --requests 4 --prompt-len 16 --max-new 8
+  PYTHONPATH=src python -m repro.launch.serve --arch stablelm-1.6b --full
+
+``--full`` serves the published widths (weights are random, from
+``--seed``).  Exits non-zero unless every request ends ``done``.
 """
 
 from __future__ import annotations
 
 import argparse
+import sys
 import time
 
 import jax
 import numpy as np
 
 from repro.configs import get_config, get_reduced
+from repro.launch.compile_cache import setup_compile_cache
 from repro.models import model as M
 from repro.serve.engine import Request, ServeEngine
 
 
-def main():
+def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--full", action="store_true")
@@ -26,7 +33,8 @@ def main():
     ap.add_argument("--max-new", type=int, default=8)
     ap.add_argument("--temperature", type=float, default=0.0)
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    setup_compile_cache()
 
     cfg = get_config(args.arch) if args.full else get_reduced(args.arch)
     params = M.init_params(cfg, jax.random.PRNGKey(args.seed))
@@ -44,10 +52,12 @@ def main():
     dt = time.time() - t0
     total_new = sum(len(r.generated) for r in done.values())
     for uid, r in sorted(done.items()):
-        print(f"req {uid}: {r.generated}")
+        print(f"req {uid}: {r.status} {r.generated}"
+              + (f" ({r.error})" if r.error else ""))
     print(f"{total_new} tokens in {dt:.2f}s "
           f"({total_new/dt:.1f} tok/s incl. compile)")
+    return 0 if all(r.status == "done" for r in done.values()) else 1
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

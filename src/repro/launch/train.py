@@ -22,6 +22,7 @@ import jax.numpy as jnp
 from repro.checkpoint.manager import CheckpointManager
 from repro.configs import get_config, get_reduced
 from repro.data.pipeline import DataConfig, batch_for_model
+from repro.launch.compile_cache import setup_compile_cache
 from repro.obs import get_metrics, span
 from repro.optim import adamw
 from repro.runtime.fault import HeartbeatMonitor
@@ -116,6 +117,7 @@ def main():
     ap.add_argument("--fail-at", type=int, default=None,
                     help="inject a crash at this step (fault-tolerance demo)")
     args = ap.parse_args()
+    setup_compile_cache()
     _, losses = run_training(
         args.arch, args.steps, full=args.full, seq_len=args.seq_len,
         global_batch=args.global_batch, microbatches=args.microbatches,

@@ -10,6 +10,8 @@ import sys
 
 if __name__ == "__main__":
     ndev = int(sys.argv[1]) if len(sys.argv) > 1 else 8
+    # A CPU rehearsal by design: pin the platform so it never takes a chip.
+    os.environ["JAX_PLATFORMS"] = "cpu"
     os.environ["XLA_FLAGS"] = (
         f"--xla_force_host_platform_device_count={ndev} "
         + os.environ.get("XLA_FLAGS", "")
@@ -22,7 +24,7 @@ import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
 from repro.core import distributed as dist  # noqa: E402
-from repro.launch.mesh import make_mesh_compat  # noqa: E402
+from repro.launch.mesh import make_mesh  # noqa: E402
 from repro.obs.ledger import GemmLedger, reset_ledger, set_ledger  # noqa: E402
 from repro.quant import quantize  # noqa: E402
 from repro.serve import tp  # noqa: E402
@@ -37,7 +39,7 @@ def main(ndev: int) -> int:
     assert len(jax.devices()) == ndev, jax.devices()
     failures = 0
     cfg = tp.TpDecodeConfig(d_model=64, n_heads=4, d_ff=128)
-    mesh = make_mesh_compat((2, ndev // 2), ("data", "model"))
+    mesh = make_mesh((2, ndev // 2), ("data", "model"))
     key = jax.random.PRNGKey(0)
     params = tp.init_tp_params(cfg, key)
     B, T = 4, 3

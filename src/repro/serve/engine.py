@@ -1,8 +1,8 @@
-"""Batched serving engine: slot-based continuous batching over
-prefill/decode steps (the serving-side integration of the framework).
+"""Serving engine: the serving-side integration of the framework.
 
-Fixed-capacity decode batch; finished slots are refilled from the queue
-(prefill runs per-request, decode runs for the whole batch every step).
+Requests are served one at a time: a prefill at batch 1, then a decode
+loop at batch 1 (``batch_size`` sizes only the warmup shapes and the
+KV page pool).
 Sampling is greedy or temperature-based and fully deterministic given the
 seed.  KV caches are the per-arch pytrees from models/ (compressed MLA
 cache, rolling SWA cache, O(1) SSM state — whatever the config dictates).
@@ -270,11 +270,8 @@ class ServeEngine:
 
     def _sample_inputs(self, rng: np.random.RandomState, length: int):
         """One prefill input of sample traffic (tokens or embeds)."""
-        toks = jnp.asarray(rng.randint(0, self.cfg.vocab_size,
-                                       (1, length)), jnp.int32)
-        if self.cfg.frontend == "tokens":
-            return {"tokens": toks}
-        return {"embeds": self._sample_table[toks]}
+        return self._model_input(jnp.asarray(
+            rng.randint(0, self.cfg.vocab_size, (1, length)), jnp.int32))
 
     def _calibrate_activations(self, n_batches: int):
         """The classic post-training static calibration loop: forward a
@@ -553,27 +550,70 @@ class ServeEngine:
         finally:
             self.kv_pool.free(req.uid)
 
+    def _model_input(self, toks: jax.Array) -> Dict[str, jax.Array]:
+        """Prefill/decode input for ``(1, L)`` token ids (tokens or the
+        demo embeddings of an embeds-frontend config)."""
+        if self.cfg.frontend == "tokens":
+            return {"tokens": toks}
+        return {"embeds": self._sample_table[toks]}
+
+    def _prefill_request(self, params, uid: int, prompt: np.ndarray,
+                         n_new: int):
+        """Prefill one prompt on the engine's compiled step; on the paged
+        path first bind pages for ``len(prompt) + n_new`` tokens under
+        ``uid`` (the caller frees them)."""
+        pre_in = self._model_input(jnp.asarray(prompt, jnp.int32)[None, :])
+        if self.kv_pool is None:
+            return self._prefill(params, pre_in)
+        page_ids = self.kv_pool.alloc(uid, len(prompt) + n_new)
+        cache0 = self._kvc.model_assign_sequence(self.kv_cache, 0, page_ids)
+        return self._prefill_paged(params, pre_in, cache0)
+
+    def teacher_forced_logits(self, prompt: np.ndarray,
+                              tokens) -> np.ndarray:
+        """Logits of the last prompt position, then of one decode step
+        per forced token, on the engine's own compiled steps and cache.
+
+        Returns ``(1 + len(tokens), vocab)`` fp32 (codebook 0 for
+        multi-codebook configs).  Feeding every path the same tokens
+        compares logits step by step, where greedy outputs of random
+        weights part at the first near-tie.
+        """
+        params = self._params_for(self.base_level)
+        uid = -1 - len(self.done)       # never a request uid
+        rows = []
+
+        def keep(logits):
+            row = logits[0, -1]
+            if self.cfg.n_codebooks > 1:
+                row = row[0]
+            rows.append(np.asarray(row[:self.cfg.vocab_size], np.float32))
+
+        try:
+            logits, cache = self._prefill_request(params, uid, prompt,
+                                                  len(tokens) + 1)
+            keep(logits)
+            for i, tok in enumerate(tokens):
+                logits, cache = self._decode(
+                    params, self._model_input(jnp.full((1, 1), tok,
+                                                       jnp.int32)),
+                    cache, jnp.int32(len(prompt) + i))
+                keep(logits)
+        finally:
+            if self.kv_pool is not None:
+                self.kv_pool.free(uid)
+        return np.stack(rows)
+
     def _serve_attempt(self, req: Request, params,
                        deadline_t: Optional[float], *, paged: bool) -> None:
         h = self._h
         ledger = get_ledger()
         plan = active_fault_plan()
         t_att = time.perf_counter()
-        toks = jnp.asarray(req.prompt, jnp.int32)[None, :]
-        if self.cfg.frontend == "tokens":
-            pre_in = {"tokens": toks}
-        else:
-            pre_in = {"embeds": self._sample_table[toks]}
-        with span("serve.prefill", uid=req.uid, length=toks.shape[1],
+        with span("serve.prefill", uid=req.uid, length=len(req.prompt),
                   paged=paged), ledger.step("prefill"):
-            if paged:
-                page_ids = self.kv_pool.alloc(
-                    req.uid, len(req.prompt) + req.max_new_tokens)
-                cache0 = self._kvc.model_assign_sequence(
-                    self.kv_cache, 0, page_ids)
-                logits, cache = self._prefill_paged(params, pre_in, cache0)
-            else:
-                logits, cache = self._prefill(params, pre_in)
+            logits, cache = self._prefill_request(
+                params, req.uid, req.prompt, req.max_new_tokens)
             self._ensure_finite(logits)
             nxt = self._sample(logits, req.temperature)
         t_first = time.perf_counter()
@@ -581,7 +621,7 @@ class ServeEngine:
         h["prefill_s"].inc(t_first - t_att)
         req.generated.append(nxt)
         h["tokens"].inc()
-        pos = toks.shape[1]
+        pos = len(req.prompt)
         with span("serve.decode", uid=req.uid,
                   tokens=req.max_new_tokens - 1):
             for _ in range(req.max_new_tokens - 1):
@@ -597,12 +637,8 @@ class ServeEngine:
                 if fault is not None and fault.transient:
                     raise TransientServeError(
                         f"injected transient failure (request {req.uid})")
-                if self.cfg.frontend == "tokens":
-                    step_in = {"tokens": jnp.full((1, 1), nxt,
-                                                  jnp.int32)}
-                else:
-                    step_in = {"embeds": self._sample_table[
-                        jnp.full((1, 1), nxt, jnp.int32)]}
+                step_in = self._model_input(jnp.full((1, 1), nxt,
+                                                     jnp.int32))
                 with ledger.step("decode"):
                     logits, cache = self._decode(
                         params, step_in, cache, jnp.int32(pos))

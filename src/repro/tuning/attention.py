@@ -157,9 +157,9 @@ def _tune_paged(heads: int, kv_heads: int, head_dim: int, seq_len: int,
             continue
         NP = sb // page
         P = B * NP
-        kp = jnp.asarray(rng.integers(-127, 128, size=(P, page, kv_heads,
+        kp = jnp.asarray(rng.integers(-127, 128, size=(P, kv_heads, page,
                                                        head_dim), dtype=np.int8))
-        vp = jnp.asarray(rng.integers(-127, 128, size=(P, page, kv_heads,
+        vp = jnp.asarray(rng.integers(-127, 128, size=(P, kv_heads, page,
                                                        head_dim), dtype=np.int8))
         sc = jnp.full((P,), 0.02, jnp.float32)
         tables = jnp.arange(P, dtype=jnp.int32).reshape(B, NP)

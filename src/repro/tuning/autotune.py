@@ -35,10 +35,7 @@ DEFAULT_ITERS = 3
 
 def _auto_interpret() -> bool:
     """Pallas interpret mode unless a real TPU backend is attached."""
-    try:
-        return jax.default_backend() != "tpu"
-    except Exception:  # repro: noqa RPR004 -- backend probe: no backend at all means interpret
-        return True
+    return jax.default_backend() != "tpu"
 
 
 def _pad_to_tiles(x: jax.Array, r0: int, r1: int) -> jax.Array:

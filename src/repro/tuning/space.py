@@ -28,7 +28,8 @@ from typing import Iterable, List, Sequence, Tuple
 import jax.numpy as jnp
 
 from repro.core.hardware import TpuTarget, V5E
-from repro.core.io_model import (TileConfig, effective_intensity,
+from repro.core.io_model import (VMEM_BUDGET_FRACTION, TileConfig,
+                                 effective_intensity,
                                  io_lower_bound_elements, io_volume_elements,
                                  round_up_to, solve_tile_config,
                                  tile_vmem_bytes, vmem_quantum)
@@ -62,7 +63,7 @@ def candidate_tile_configs(
     dtype_in=jnp.bfloat16,
     dtype_acc=jnp.float32,
     hw: TpuTarget = V5E,
-    vmem_fraction: float = 0.75,
+    vmem_fraction: float = VMEM_BUDGET_FRACTION,
     top_n: int = DEFAULT_TOP_N,
     orders: Sequence[str] = ("k_inner",),
     semiring: str = "plus_times",

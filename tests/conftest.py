@@ -1,18 +1,5 @@
 """Test session config: 1 CPU device (the dry-run forces 512 in its own
-subprocess), xla gemm mode by default.
-
-If the real ``hypothesis`` package is missing (this container doesn't ship
-it and installs are not allowed), fall back to the deterministic shim in
-``tests/_stubs`` so property tests still collect and run.
-"""
-
-import pathlib
-import sys
-
-try:  # pragma: no cover - depends on container contents
-    import hypothesis  # noqa: F401
-except ImportError:
-    sys.path.insert(0, str(pathlib.Path(__file__).parent / "_stubs"))
+subprocess), xla gemm mode by default."""
 
 import numpy as np
 import pytest
@@ -22,13 +9,13 @@ from repro.core import set_gemm_fallback, set_gemm_mode
 
 @pytest.fixture(autouse=True)
 def _default_gemm_mode():
-    """xla dispatch, kernel->XLA fallback OFF (a kernel bug must fail its
-    parity test, not silently serve the oracle); fault-tolerance tests
-    opt back in with ``gemm_fallback(True)``."""
+    """xla dispatch, kernel->XLA fallback off (its default: a kernel bug
+    must fail its parity test, not silently serve the oracle);
+    fault-tolerance tests opt in with ``gemm_fallback(True)``."""
     set_gemm_mode("xla")
     set_gemm_fallback(False)
     yield
-    set_gemm_fallback(True)
+    set_gemm_fallback(False)
 
 
 @pytest.fixture(autouse=True)

@@ -219,9 +219,9 @@ def test_poisoned_cache_entry_raises_vmem001_not_pallas():
 
 def test_dist_matmul_rejects_unknown_schedule():
     from repro.core import dist_matmul
-    from repro.launch.mesh import make_mesh_compat
+    from repro.launch.mesh import make_mesh
 
-    mesh = make_mesh_compat((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     a = jnp.ones((8, 16), jnp.float32)
     b = jnp.ones((16, 8), jnp.float32)
     with pytest.raises(ProgramValidationError, match="DIST004"):

@@ -173,15 +173,15 @@ path, phase = sys.argv[1], sys.argv[2]
 mgr = CheckpointManager(path)
 t = {"w": jnp.arange(64, dtype=jnp.float32).reshape(8, 8)}
 if phase == "save":
-    from repro.launch.mesh import make_mesh_compat
-    mesh = make_mesh_compat((len(jax.devices()),), ("data",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((len(jax.devices()),), ("data",))
     sh = NamedSharding(mesh, P("data", None))
     t = {"w": jax.device_put(t["w"], sh)}
     mgr.save(1, t)
     print("SAVED", len(jax.devices()))
 else:
-    from repro.launch.mesh import make_mesh_compat
-    mesh = make_mesh_compat((len(jax.devices()),), ("data",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((len(jax.devices()),), ("data",))
     sh = {"w": NamedSharding(mesh, P("data", None))}
     like = {"w": jnp.zeros((8, 8), jnp.float32)}
     r = mgr.restore(like, shardings=sh)

@@ -145,7 +145,7 @@ def test_chaos_fallback_dist_matmul():
     import numpy as np
 
     from repro.core import dist_matmul, gemm_fallback
-    from repro.launch.mesh import make_mesh_compat
+    from repro.launch.mesh import make_mesh
     from repro.obs import get_metrics
     from repro.runtime.fault import FaultPlan
 
@@ -154,7 +154,7 @@ def test_chaos_fallback_dist_matmul():
         m = snap.get("gemm.fallback_total")
         return m.get("labels", {}).get("stage=dist_matmul", 0) if m else 0
 
-    mesh = make_mesh_compat((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     a = jnp.asarray(np.random.RandomState(0).randn(8, 16), jnp.float32)
     b = jnp.asarray(np.random.RandomState(1).randn(16, 8), jnp.float32)
     want = np.asarray(jnp.dot(a, b))
@@ -196,10 +196,10 @@ def test_shard_gemm_workloads():
 def test_dist_operand_specs():
     from jax.sharding import PartitionSpec as P
 
-    from repro.launch.mesh import make_mesh_compat
+    from repro.launch.mesh import make_mesh
     from repro.sharding.rules import dist_operand_specs
 
-    mesh = make_mesh_compat((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     specs = dist_operand_specs(("embed", "qkv"), (64, 64), mesh)
     assert specs == (P("data", "model"), P(None, "model"),
                      P("data", "model"))
@@ -207,5 +207,5 @@ def test_dist_operand_specs():
     assert dist_operand_specs(("qkv", "embed"), (64, 64), mesh) is not None
     # non-2D weights (or meshes without the tp axis) cannot ride
     assert dist_operand_specs(("embed",), (64,), mesh) is None
-    no_tp = make_mesh_compat((1,), ("data",))
+    no_tp = make_mesh((1,), ("data",))
     assert dist_operand_specs(("embed", "qkv"), (64, 64), no_tp) is None

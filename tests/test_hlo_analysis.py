@@ -70,8 +70,8 @@ import sys
 sys.path.insert(0, %r)
 from repro.launch import hlo_analysis as H
 
-from repro.launch.mesh import make_mesh_compat
-mesh = make_mesh_compat((2, 4), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 4), ("data", "model"))
 L, D = 7, 256
 
 def f(ws, x):

@@ -97,3 +97,28 @@ def test_solver_burst_aware_bk():
     assert t_bf16.bk * 2 >= 512          # >= one HBM transaction per row
     t_int8 = solve_tile_config(16384, 16384, 16384, dtype_in=jnp.int8)
     assert t_int8.bk * 1 >= 512
+
+
+@pytest.mark.parametrize("kind, name", [("TPU v5 lite", "tpu-v5e"),
+                                        ("TPU v5", "tpu-v5p")])
+def test_target_resolves_from_device_kind(kind, name):
+    from repro.core import target_for_kind
+
+    assert target_for_kind(kind).name == name
+
+
+def test_unknown_device_kind_raises():
+    from repro.core import target_for_kind
+
+    with pytest.raises(ValueError, match="no peak table entry"):
+        target_for_kind("TPU v9 imaginary")
+
+
+def test_kernel_vmem_limit_covers_plan_and_caps_at_vmem():
+    from repro.core.io_model import kernel_vmem_limit_bytes
+
+    planned = tile_vmem_bytes(512, 1024, 512, 2)
+    limit = kernel_vmem_limit_bytes(planned, 512, 1024)
+    assert planned + 512 * 1024 * 4 < limit <= V5E.vmem_bytes
+    assert kernel_vmem_limit_bytes(V5E.vmem_bytes, 512, 1024) == \
+        V5E.vmem_bytes

@@ -152,8 +152,8 @@ def _paged_pool(seed, lens, *, page, n_pages, Hkv, D, shuffle=True):
     B = len(lens)
     NP = max(-(-l // page) for l in lens)
     order = rng.permutation(n_pages) if shuffle else np.arange(n_pages)
-    kp = np.zeros((n_pages, page, Hkv, D), np.int8)
-    vp = np.zeros((n_pages, page, Hkv, D), np.int8)
+    kp = np.zeros((n_pages, Hkv, page, D), np.int8)   # head-major pages
+    vp = np.zeros((n_pages, Hkv, page, D), np.int8)
     ksc = np.zeros(n_pages, np.float32)
     vsc = np.zeros(n_pages, np.float32)
     tables = np.full((B, NP), -1, np.int32)
@@ -164,10 +164,10 @@ def _paged_pool(seed, lens, *, page, n_pages, Hkv, D, shuffle=True):
         vf = rng.randn(L, Hkv, D).astype(np.float32)
         npg = -(-L // page)
         pad = npg * page - L
-        kfp = np.pad(kf, ((0, pad), (0, 0), (0, 0))).reshape(npg, page,
-                                                             Hkv, D)
-        vfp = np.pad(vf, ((0, pad), (0, 0), (0, 0))).reshape(npg, page,
-                                                             Hkv, D)
+        kfp = np.pad(kf, ((0, pad), (0, 0), (0, 0))).reshape(
+            npg, page, Hkv, D).transpose(0, 2, 1, 3)
+        vfp = np.pad(vf, ((0, pad), (0, 0), (0, 0))).reshape(
+            npg, page, Hkv, D).transpose(0, 2, 1, 3)
         for j in range(npg):
             pid = order[nxt]
             nxt += 1
@@ -179,10 +179,10 @@ def _paged_pool(seed, lens, *, page, n_pages, Hkv, D, shuffle=True):
                 scales[pid] = sc
         deq_k.append((kp[tables[b, :npg]].astype(np.float32)
                       * ksc[tables[b, :npg], None, None, None]
-                      ).reshape(npg * page, Hkv, D)[:L])
+                      ).transpose(0, 2, 1, 3).reshape(npg * page, Hkv, D)[:L])
         deq_v.append((vp[tables[b, :npg]].astype(np.float32)
                       * vsc[tables[b, :npg], None, None, None]
-                      ).reshape(npg * page, Hkv, D)[:L])
+                      ).transpose(0, 2, 1, 3).reshape(npg * page, Hkv, D)[:L])
     return (jnp.asarray(kp), jnp.asarray(vp), jnp.asarray(ksc),
             jnp.asarray(vsc), jnp.asarray(tables),
             jnp.asarray(np.asarray(lens, np.int32)), deq_k, deq_v)

@@ -3,6 +3,7 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.configs import get_reduced
 from repro.models import model as M
@@ -42,3 +43,62 @@ def test_deterministic_sampling():
                            temperature=0.8))
         outs.append(eng.run()[1].generated)
     assert outs[0] == outs[1]
+
+
+@pytest.mark.parametrize("paged", [False, True], ids=["slab", "paged"])
+def test_teacher_forced_logits_match_forward(paged):
+    """The engine's teacher-forced logits (prefill, then one decode step
+    per forced token, through its compiled steps and cache) equal a full
+    forward over the same tokens; the paged int8 cache within its
+    quantization noise."""
+    cfg = get_reduced("stablelm-1.6b")
+    params = M.init_params(cfg, jax.random.PRNGKey(0))
+    prompt = np.arange(8) % cfg.vocab_size
+    forced = [3, 17, 5]
+    eng = ServeEngine(params, cfg, batch_size=1, max_len=32,
+                      warmup_gemms=False, paged_kv=paged,
+                      kv_page_size=8 if paged else 0)
+    got = eng.teacher_forced_logits(prompt, forced)
+    assert got.shape == (1 + len(forced), cfg.vocab_size)
+    logits, _, _ = M.forward(
+        params, {"tokens": jnp.asarray([list(prompt) + forced], jnp.int32)},
+        cfg, mode="train")
+    want = np.asarray(logits[0, len(prompt) - 1:, :cfg.vocab_size])
+    tol = 3e-2 if paged else 1e-3
+    np.testing.assert_allclose(got, want, atol=tol * np.abs(want).max())
+    if paged:   # the pages bound for scoring went back to the pool
+        assert eng.kv_pool.n_free == eng.kv_pool.n_pages
+
+
+@pytest.mark.parametrize("nan_at, rc", [(None, 0), (0, 1)],
+                         ids=["all_done", "one_failed"])
+def test_launch_serve_exit_code(monkeypatch, nan_at, rc):
+    """The launcher exits 0 only when every request ends ``done``."""
+    from repro.launch import serve as launch_serve
+    from repro.runtime.fault import FaultPlan
+
+    monkeypatch.setattr(launch_serve, "setup_compile_cache", lambda: None)
+    argv = ["--arch", "stablelm-1.6b", "--requests", "2",
+            "--prompt-len", "4", "--max-new", "3"]
+    plan = FaultPlan(nan_decode_at=() if nan_at is None else (nan_at,))
+    with plan:
+        assert launch_serve.main(argv) == rc
+
+
+def test_compile_cache_dir_rule(monkeypatch, tmp_path):
+    """The environment's directory is left to JAX; without it the cache
+    goes to one fixed directory."""
+    from repro.launch import compile_cache as cc
+
+    prev = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv(cc.ENV_VAR, str(tmp_path / "env"))
+    assert cc.setup_compile_cache() == (str(tmp_path / "env"), True)
+    assert jax.config.jax_compilation_cache_dir == prev
+    monkeypatch.delenv(cc.ENV_VAR)
+    monkeypatch.setattr(cc, "DEFAULT_DIR", tmp_path / "fixed")
+    try:
+        assert cc.setup_compile_cache() == (str(tmp_path / "fixed"), False)
+        assert jax.config.jax_compilation_cache_dir == str(tmp_path / "fixed")
+        assert (tmp_path / "fixed").is_dir()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
