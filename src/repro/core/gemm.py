@@ -36,6 +36,7 @@ from repro.kernels.epilogue import Epilogue, IDENTITY, apply_reference
 from repro.kernels.program import (GemmProgramSpec, NO_PROLOGUE,
                                    PrologueSpec, RmsPrologue,
                                    apply_rms_reference, rms_row_scale)
+from repro.obs.trace import kernel_scope
 
 _state = threading.local()
 
@@ -193,6 +194,7 @@ def _preflight(res, tag: str, hw: TpuTarget, *, dtype, dtype_b=None,
                    scale_block=scale_block, act_block=act_block)
 
 
+@kernel_scope("gemm")
 def dist_local_matmul(a, b, *, tile: Optional[TileConfig] = None,  # repro: noqa RPR002 -- dist_matmul records once per collective dispatch
                       mode: Optional[str] = None, acc_dtype=jnp.float32):
     """One ring-step local GEMM of a distributed schedule.
@@ -284,6 +286,7 @@ def _maybe_record_activation(quant, x: jax.Array,
     ctx.record(quant.shape, xo)
 
 
+@kernel_scope("gemm")
 def ca_matmul(
     x: jax.Array,
     w=None,
@@ -474,6 +477,7 @@ def ca_matmul(
     return y2.reshape(*lead, n).astype(out_dtype)
 
 
+@kernel_scope("gemm")
 def ca_glu_matmul(
     x: jax.Array,
     w_gate,
@@ -624,6 +628,7 @@ def ca_glu_matmul(
     return y2.reshape(*lead, n).astype(out_dtype)
 
 
+@kernel_scope("gemm")
 def ca_expert_matmul(
     x: jax.Array,
     w: jax.Array,
@@ -668,6 +673,7 @@ def ca_expert_matmul(
     return jnp.stack(ys, axis=-3)
 
 
+@kernel_scope("gemm")
 def ca_expert_glu_matmul(
     x: jax.Array,
     w_gate: jax.Array,

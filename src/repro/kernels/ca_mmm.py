@@ -580,6 +580,7 @@ def ca_gemm_program(
             vmem_limit_bytes=io_model.kernel_vmem_limit_bytes(
                 planned, bm, bn)),
         interpret=interpret,
+        name="ca_gemm_program",
     )(*operands)
     if len(out) == 1:
         return out[0]
@@ -688,4 +689,5 @@ def ca_mmm_k_outer(
             dimension_semantics=("arbitrary", "parallel", "parallel"),
         ),
         interpret=interpret,
+        name="ca_mmm_k_outer",
     )(a, b).astype(out_dtype)

@@ -161,6 +161,7 @@ def flash_attention_tpu(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_attention",
     )(qpos_r, kpos_r, qr, kr, vr)
 
     out = out.reshape(B, Hkv, nq, G, qc, Dv).transpose(0, 2, 4, 1, 3, 5) \
@@ -304,5 +305,6 @@ def paged_flash_attention_tpu(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="paged_flash_attention",
     )(tables, seq_lens.astype(jnp.int32), ksc, vsc, qr, k_pages, v_pages)
     return out.reshape(B, Hkv, G, Dv).reshape(B, H, Dv)

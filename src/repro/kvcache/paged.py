@@ -35,6 +35,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs.trace import kernel_scope
+
 _EPS = 1e-12
 _QMAX = 127.0  # symmetric int8 grid, repro.quant.scales._FMT_MAX["int8"]
 
@@ -109,6 +111,7 @@ def model_release_sequence(cache, b: int):
 # Inserts
 # ---------------------------------------------------------------------------
 
+@kernel_scope("kv_write")
 def paged_prefill_insert(cache: Dict[str, jax.Array], k_new: jax.Array,
                          v_new: jax.Array) -> Dict[str, jax.Array]:
     """Bulk-insert a prefill's K/V into the sequence's mapped pages.
@@ -177,6 +180,7 @@ def _append_token(pool: jax.Array, scales: jax.Array, pid: jax.Array,
     return pool, scales.at[pid].set(new_sc)
 
 
+@kernel_scope("kv_write")
 def paged_decode_insert(cache: Dict[str, jax.Array], k_new: jax.Array,
                         v_new: jax.Array) -> Dict[str, jax.Array]:
     """Append one decode token ``(B, 1, Hkv, D)`` per sequence.
@@ -237,6 +241,7 @@ def _auto_mode() -> str:
     return "pallas" if jax.default_backend() == "tpu" else "xla"
 
 
+@kernel_scope("attn")
 def paged_attention(q: jax.Array, cache: Dict[str, jax.Array], *,
                     window: Optional[int] = None,
                     scale: Optional[float] = None,

@@ -24,6 +24,7 @@ from repro.kernels.epilogue import Epilogue
 from repro import kvcache as kvc
 from repro.models import common as cm
 from repro.models.common import Defs, ParamDef
+from repro.obs.trace import kernel_scope
 
 NEG = -1e30
 
@@ -41,6 +42,7 @@ def _pad_axis(x, mult, axis, value=0):
     return jnp.pad(x, widths, constant_values=value)
 
 
+@kernel_scope("attn")
 def flash_attention(
     q: jax.Array,            # (B, Lq, H, Dq)
     k: jax.Array,            # (B, S, Hkv, Dq)
@@ -115,6 +117,7 @@ def flash_attention(
     return out[:, :Lq]
 
 
+@kernel_scope("attn")
 def dense_attention(q, k, v, *, q_positions, kv_positions, causal=True,
                     window=None, scale=None) -> jax.Array:
     """Unchunked scores — used for decode (Lq == 1) and tiny smoke runs."""
@@ -157,6 +160,7 @@ def cache_len_for(cfg: ModelConfig, seq_len: int) -> int:
     return seq_len
 
 
+@kernel_scope("kv_write")
 def kv_cache_insert(cache, k_new, v_new, step: jax.Array):
     """Insert one token (B, 1, Hkv, D) at rolling slot ``step % C``."""
     C = cache["k"].shape[1]
@@ -173,6 +177,7 @@ def kv_cache_insert(cache, k_new, v_new, step: jax.Array):
     return cache
 
 
+@kernel_scope("kv_write")
 def kv_cache_from_prefill(k, v, positions, cache_len: int):
     """Build a cache from full-sequence prefill k/v.
 
@@ -352,33 +357,35 @@ def mla_apply(p, x, cfg: ModelConfig, *, positions, cache=None, step=None,
     if mode == "decode":
         assert cache is not None and step is not None
         # cache: {"c": (B, C, r), "k_rope": (B, C, rope), "pos": (B, C)}
-        C = cache["c"].shape[1]
-        slot = jnp.mod(step, C)
-        cache = dict(cache)
-        cache["c"] = jax.lax.dynamic_update_slice_in_dim(
-            cache["c"], c_kv, slot, axis=1)
-        cache["k_rope"] = jax.lax.dynamic_update_slice_in_dim(
-            cache["k_rope"], k_rope, slot, axis=1)
-        cache["pos"] = jax.lax.dynamic_update_slice_in_dim(
-            cache["pos"], jnp.broadcast_to(step, (B, 1)).astype(jnp.int32),
-            slot, axis=1)
-        # Absorbed scores: q_nope -> lora space.
-        q_abs = jnp.einsum("blhn,rhn->blhr", q_nope, w_uk,
-                           preferred_element_type=jnp.float32).astype(dt)
-        s = jnp.einsum("blhr,bsr->bhls", q_abs, cache["c"],
-                       preferred_element_type=jnp.float32)
-        s += jnp.einsum("blhn,bsn->bhls", q_rope, cache["k_rope"],
-                        preferred_element_type=jnp.float32)
-        s *= scale
-        mask = (cache["pos"][:, None, :] >= 0) & (
-            cache["pos"][:, None, :] <= pos2d[:, :, None])
-        s = jnp.where(mask[:, None], s, NEG)
-        pattn = jax.nn.softmax(s, axis=-1)
-        pattn = jnp.where(mask[:, None], pattn, 0.0)
-        o_c = jnp.einsum("bhls,bsr->blhr", pattn.astype(dt), cache["c"],
-                         preferred_element_type=jnp.float32).astype(dt)
-        out = jnp.einsum("blhr,rhv->blhv", o_c, w_uv,
-                         preferred_element_type=jnp.float32).astype(dt)
+        with jax.named_scope("kv_write"):
+            C = cache["c"].shape[1]
+            slot = jnp.mod(step, C)
+            cache = dict(cache)
+            cache["c"] = jax.lax.dynamic_update_slice_in_dim(
+                cache["c"], c_kv, slot, axis=1)
+            cache["k_rope"] = jax.lax.dynamic_update_slice_in_dim(
+                cache["k_rope"], k_rope, slot, axis=1)
+            cache["pos"] = jax.lax.dynamic_update_slice_in_dim(
+                cache["pos"], jnp.broadcast_to(step, (B, 1)).astype(jnp.int32),
+                slot, axis=1)
+        with jax.named_scope("attn"):
+            # Absorbed scores: q_nope -> lora space.
+            q_abs = jnp.einsum("blhn,rhn->blhr", q_nope, w_uk,
+                               preferred_element_type=jnp.float32).astype(dt)
+            s = jnp.einsum("blhr,bsr->bhls", q_abs, cache["c"],
+                           preferred_element_type=jnp.float32)
+            s += jnp.einsum("blhn,bsn->bhls", q_rope, cache["k_rope"],
+                            preferred_element_type=jnp.float32)
+            s *= scale
+            mask = (cache["pos"][:, None, :] >= 0) & (
+                cache["pos"][:, None, :] <= pos2d[:, :, None])
+            s = jnp.where(mask[:, None], s, NEG)
+            pattn = jax.nn.softmax(s, axis=-1)
+            pattn = jnp.where(mask[:, None], pattn, 0.0)
+            o_c = jnp.einsum("bhls,bsr->blhr", pattn.astype(dt), cache["c"],
+                             preferred_element_type=jnp.float32).astype(dt)
+            out = jnp.einsum("blhr,rhv->blhv", o_c, w_uv,
+                             preferred_element_type=jnp.float32).astype(dt)
         new_cache = cache
     else:
         kv = jnp.einsum("blr,rhn->blhn", c_kv,

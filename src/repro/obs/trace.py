@@ -1,24 +1,36 @@
 """Tracing spans: Chrome-trace-event / Perfetto-compatible JSONL.
 
 ``span("name", **attrs)`` wraps any region of host code; when tracing is
-enabled each completed span appends one complete ("ph": "X") trace event
-line to the output file, which loads directly in Perfetto / chrome://
-tracing (the writer emits the Trace Event *array* format, whose closing
-bracket is optional by spec — so the file is line-appendable, crash-safe,
-and still a valid JSON-array trace).
+enabled each completed span becomes one complete ("ph": "X") trace
+event.  Events are kept in memory and written out, one JSON line each,
+by :func:`flush`, by :func:`disable_tracing` and at process exit, so
+the hot path does no formatting and no file I/O.  Past
+:data:`MAX_BUFFERED` held events a daemon thread writes them, and the
+span that crossed the limit returns at once.  The file is in the Trace Event *array*
+format, whose closing bracket is optional by spec — so it is
+line-appendable and still a valid JSON-array trace that loads directly
+in Perfetto / chrome://tracing.
 
 Enable with ``REPRO_TRACE=<path>`` in the environment (``1`` means the
 default ``trace.jsonl``) or programmatically via :func:`enable_tracing`.
 Disabled — the default — a span is a shared no-op context manager: no
 file is opened, no event object is built, no lock is taken.
 
-When a real ``jax.profiler`` is present each span additionally enters a
-``TraceAnnotation`` so device profiles (``jax.profiler.trace``) carry the
-same region names; on hosts without one this degrades silently.
+When a real ``jax.profiler`` is present each enabled span also enters a
+``TraceAnnotation`` of the same name, so device profiles
+(``jax.profiler.trace``) carry the program's spans on the device
+trace's clock; on hosts without one this degrades silently.
+
+:func:`kernel_scope` is the compiled-program half of the same naming: it
+puts a function's ops under a ``jax.named_scope``, which the device
+trace reports as each op's ``tf_op`` path.
 """
 
 from __future__ import annotations
 
+import atexit
+import collections
+import functools
 import json
 import os
 import threading
@@ -27,37 +39,78 @@ from typing import Any, Dict, List, Optional
 
 _ENV_TRACE = "REPRO_TRACE"
 DEFAULT_TRACE_PATH = "trace.jsonl"
+# Held events past which a writer thread starts: several times what a
+# minute of serving records (four spans per token, about 10k), so a run
+# of that length writes once, at its end.
+MAX_BUFFERED = 1 << 16
+
+
+def _annotation_class():
+    """``jax.profiler.TraceAnnotation`` when importable, else None."""
+    try:  # deferred: obs must import without jax on the path
+        from jax.profiler import TraceAnnotation
+    except Exception:  # repro: noqa RPR004 -- pragma: no cover, import probe of an optional jax API
+        return None
+    return TraceAnnotation
 
 
 class _Tracer:
-    """Thread-safe JSONL trace writer (one per process)."""
+    """In-memory span buffer and its JSONL file (one per process)."""
 
     def __init__(self, path: str):
         self.path = path
         self._lock = threading.Lock()
+        # (name, start_us, end_us, tid, attrs); deque appends and pops
+        # are atomic, so recording takes no lock.
+        self._events: collections.deque = collections.deque()
+        self._writing = False       # a writer thread is on its way
         self._f = open(path, "w")
         self._f.write("[\n")          # array format; "]" optional by spec
         self._f.flush()
         self.pid = os.getpid()
         self._t0 = time.perf_counter()
+        self.annotation = _annotation_class()    # resolved once
 
     def now_us(self) -> float:
         return (time.perf_counter() - self._t0) * 1e6
 
-    def emit(self, event: Dict[str, Any]) -> None:
-        line = json.dumps(event, sort_keys=True)
-        with self._lock:
-            self._f.write(line + ",\n")
+    def record(self, event: tuple) -> None:
+        self._events.append(event)
+        if len(self._events) > MAX_BUFFERED and not self._writing:
+            self._writing = True
+            threading.Thread(target=self._write_behind, daemon=True,
+                             name="repro-trace-writer").start()
+
+    def _write_behind(self) -> None:
+        try:
+            self.flush()
+        finally:
+            self._writing = False
 
     def flush(self) -> None:
+        """Write every held event, in the order they completed."""
         with self._lock:
+            if self._f.closed:
+                return
+            # Only flush pops, under the lock, so the events held now
+            # are there to pop; those recorded meanwhile wait their turn.
+            pop, lines = self._events.popleft, []
+            for _ in range(len(self._events)):
+                name, ts, end, tid, attrs = pop()
+                event = {"name": name, "ph": "X", "ts": ts,
+                         "dur": end - ts, "pid": self.pid, "tid": tid,
+                         "cat": "repro"}
+                if attrs:
+                    event["args"] = {k: _jsonable(v)
+                                     for k, v in attrs.items()}
+                lines.append(json.dumps(event, sort_keys=True) + ",\n")
+            self._f.write("".join(lines))
             self._f.flush()
 
     def close(self) -> None:
+        self.flush()
         with self._lock:
-            if not self._f.closed:
-                self._f.flush()
-                self._f.close()
+            self._f.close()
 
 
 _state_lock = threading.Lock()
@@ -65,17 +118,8 @@ _tracer: Optional[_Tracer] = None
 _env_checked = False
 
 
-def _jax_annotation(name: str):
-    """A jax.profiler.TraceAnnotation when available, else None."""
-    try:  # deferred: obs must import without jax on the path
-        from jax.profiler import TraceAnnotation
-    except Exception:  # repro: noqa RPR004 -- pragma: no cover, import probe of an optional jax API
-        return None
-    return TraceAnnotation(name)
-
-
 def enable_tracing(path: str = DEFAULT_TRACE_PATH) -> str:
-    """Start writing trace events to ``path`` (truncates). Returns path."""
+    """Start recording trace events for ``path`` (truncates). Returns path."""
     global _tracer, _env_checked
     with _state_lock:
         if _tracer is not None:
@@ -86,7 +130,7 @@ def enable_tracing(path: str = DEFAULT_TRACE_PATH) -> str:
 
 
 def disable_tracing() -> None:
-    """Stop tracing and close the output file (flushes pending events)."""
+    """Stop tracing; every recorded event is written and the file closed."""
     global _tracer, _env_checked
     with _state_lock:
         if _tracer is not None:
@@ -99,13 +143,16 @@ def tracing_enabled() -> bool:
     return _get_tracer() is not None
 
 
-def trace_path() -> Optional[str]:
-    t = _get_tracer()
-    return t.path if t is not None else None
-
-
 def flush() -> None:
+    """Write every event recorded so far (tracing stays on)."""
     t = _get_tracer()
+    if t is not None:
+        t.flush()
+
+
+@atexit.register
+def _flush_at_exit() -> None:
+    t = _tracer
     if t is not None:
         t.flush()
 
@@ -128,7 +175,7 @@ def _get_tracer() -> Optional[_Tracer]:
 
 
 class _NoopSpan:
-    """Shared do-nothing span (tracing disabled, no jax annotation)."""
+    """Shared do-nothing span (tracing disabled)."""
 
     __slots__ = ()
 
@@ -143,19 +190,17 @@ _NOOP = _NoopSpan()
 
 
 class Span:
-    """An active span: records wall duration, emits one "X" event."""
+    """An active span: records one "X" event when it ends."""
 
-    __slots__ = ("name", "attrs", "tracer", "_annotation", "_start_us",
-                 "duration_s")
+    __slots__ = ("name", "attrs", "tracer", "_annotation", "_start_us")
 
-    def __init__(self, name: str, tracer: _Tracer, annotation,
-                 attrs: Dict[str, Any]):
+    def __init__(self, name: str, tracer: _Tracer, attrs: Dict[str, Any]):
         self.name = name
         self.attrs = attrs
         self.tracer = tracer
-        self._annotation = annotation
+        self._annotation = (tracer.annotation(name)
+                            if tracer.annotation is not None else None)
         self._start_us = 0.0
-        self.duration_s = 0.0
 
     def __enter__(self):
         if self._annotation is not None:
@@ -164,20 +209,8 @@ class Span:
         return self
 
     def __exit__(self, *exc):
-        end_us = self.tracer.now_us()
-        self.duration_s = (end_us - self._start_us) * 1e-6
-        event = {
-            "name": self.name,
-            "ph": "X",
-            "ts": self._start_us,
-            "dur": end_us - self._start_us,
-            "pid": self.tracer.pid,
-            "tid": threading.get_ident() & 0x7FFFFFFF,
-            "cat": "repro",
-        }
-        if self.attrs:
-            event["args"] = {k: _jsonable(v) for k, v in self.attrs.items()}
-        self.tracer.emit(event)
+        self.tracer.record((self.name, self._start_us, self.tracer.now_us(),
+                            threading.get_ident() & 0x7FFFFFFF, self.attrs))
         if self._annotation is not None:
             self._annotation.__exit__(*exc)
         return False
@@ -200,29 +233,26 @@ def span(name: str, **attrs):
     """
     tracer = _get_tracer()
     if tracer is None:
-        # No event will be written; still forward the name to a device
-        # profiler if one is importable AND actively collecting is cheap
-        # to decide — TraceAnnotation construction itself is the cost, so
-        # skip it entirely in the disabled fast path.
         return _NOOP
-    return Span(name, tracer, _jax_annotation(name), attrs)
+    return Span(name, tracer, attrs)
 
 
-def instant(name: str, **attrs) -> None:
-    """Emit a zero-duration instant event (scope: thread)."""
-    tracer = _get_tracer()
-    if tracer is None:
-        return
-    event = {
-        "name": name, "ph": "i", "s": "t",
-        "ts": tracer.now_us(),
-        "pid": tracer.pid,
-        "tid": threading.get_ident() & 0x7FFFFFFF,
-        "cat": "repro",
-    }
-    if attrs:
-        event["args"] = {k: _jsonable(v) for k, v in attrs.items()}
-    tracer.emit(event)
+def kernel_scope(name: str):
+    """Decorator: stage the function's ops under ``jax.named_scope(name)``.
+
+    The scope only labels the compiled program (each op's name path, the
+    ``tf_op`` of a device trace), so it costs nothing when the program
+    runs.  A fresh scope is entered per call, so recursion nests cleanly.
+    """
+    def wrap(fn):
+        @functools.wraps(fn)
+        def scoped(*args, **kwargs):
+            from jax import named_scope
+
+            with named_scope(name):
+                return fn(*args, **kwargs)
+        return scoped
+    return wrap
 
 
 def read_trace(path: str) -> List[Dict[str, Any]]:

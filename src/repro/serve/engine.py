@@ -54,6 +54,9 @@ _DEGRADED_DESC = ("Quant degradations, by from/to level (per-request "
 _REJECTED_DESC = "Requests rejected/shed at admission, by policy"
 _FALLBACK_DESC = ("Kernel-path GEMM dispatch failures re-dispatched on "
                   "the XLA oracle path, by dispatch stage")
+_SYNCS_DESC = "Device-to-host reads in the token loop, by where (at)"
+_PUTS_DESC = ("Host-to-device inputs made for the model steps, by what "
+              "they carry")
 
 
 class NonFiniteLogits(RuntimeError):
@@ -201,10 +204,17 @@ class ServeEngine:
             "analytic)")
         for src in self.gemm_plan_sources.values():
             plan_counter.labels(source=src).inc()
-        self._prefill = jax.jit(
-            lambda p, b: M.prefill(p, b, cfg, max_len=max_len))
-        self._decode = jax.jit(
-            lambda p, t, c, s: M.decode_step(p, t, c, s, cfg))
+        # Named steps: the compiled modules read ``jit_serve_prefill`` /
+        # ``jit_serve_decode`` in a device trace, and every op's name
+        # path starts with ``jit(serve_decode)``.
+        def serve_prefill(p, b):
+            return M.prefill(p, b, cfg, max_len=max_len)
+
+        def serve_decode(p, t, c, s):
+            return M.decode_step(p, t, c, s, cfg)
+
+        self._prefill = jax.jit(serve_prefill)
+        self._decode = jax.jit(serve_decode)
         # Paged KV mode (docs/KVCACHE.md): variable-length sequences admit
         # against a host-side page pool instead of a max_len-sized slab;
         # int8 pages + per-page scales replace the serve-dtype cache.  The
@@ -247,9 +257,11 @@ class ServeEngine:
             self.kv_cache = M.make_paged_model_cache(
                 cfg, 1, n_pages=self.kv_pool.n_pages,
                 page_size=kv_page_size, max_pages=self.kv_max_pages_per_seq)
-            self._prefill_paged = jax.jit(
-                lambda p, b, c: M.prefill(p, b, cfg, max_len=max_len,
-                                          cache=c))
+
+            def serve_prefill_paged(p, b, c):
+                return M.prefill(p, b, cfg, max_len=max_len, cache=c)
+
+            self._prefill_paged = jax.jit(serve_prefill_paged)
         self.base_level = ("w8a8" if self.w8a8
                            else "int8w" if self.quantized else "dense")
         self._level_params: Dict[str, object] = {self.base_level: self.params}
@@ -381,17 +393,23 @@ class ServeEngine:
         if self.cfg.n_codebooks > 1:
             logits = logits[..., 0, :]  # report codebook 0 for the demo
         if temperature <= 0:
-            return int(jnp.argmax(logits[0, -1]))
-        self.key, sub = jax.random.split(self.key)
-        return int(jax.random.categorical(sub, logits[0, -1] / temperature))
+            tok = int(jnp.argmax(logits[0, -1]))
+        else:
+            self.key, sub = jax.random.split(self.key)
+            tok = int(jax.random.categorical(sub,
+                                             logits[0, -1] / temperature))
+        self._h["sync_sample"].inc()
+        return tok
 
     def _ensure_finite(self, logits: jax.Array) -> None:
         """Raise :class:`NonFiniteLogits` when the sampled row is poisoned
-        (one cheap reduction per token; the sample already syncs)."""
+        (one cheap reduction and one sync per token)."""
         if not self.check_finite:
             return
-        if not bool(jnp.all(jnp.isfinite(
-                logits[0, -1, ..., :self.cfg.vocab_size]))):
+        finite = bool(jnp.all(jnp.isfinite(
+            logits[0, -1, ..., :self.cfg.vocab_size])))
+        self._h["sync_finite"].inc()
+        if not finite:
             raise NonFiniteLogits("non-finite logits in sampled row")
 
     # -- the serve loop -----------------------------------------------------
@@ -441,6 +459,12 @@ class ServeEngine:
             "fallback": metrics.counter(
                 "gemm.fallback_total", _FALLBACK_DESC),
         }
+        syncs = metrics.counter("serve.host_syncs_total", _SYNCS_DESC)
+        puts = metrics.counter("serve.host_puts_total", _PUTS_DESC)
+        self._h.update(sync_finite=syncs.labels(at="finite"),
+                       sync_sample=syncs.labels(at="sample"),
+                       put_token=puts.labels(what="token"),
+                       put_pos=puts.labels(what="pos"))
         tokens = self._h["tokens"]
         t_run = time.perf_counter()
         while self.queue:
@@ -557,15 +581,16 @@ class ServeEngine:
             return {"tokens": toks}
         return {"embeds": self._sample_table[toks]}
 
-    def _prefill_request(self, params, uid: int, prompt: np.ndarray,
-                         n_new: int):
-        """Prefill one prompt on the engine's compiled step; on the paged
-        path first bind pages for ``len(prompt) + n_new`` tokens under
-        ``uid`` (the caller frees them)."""
-        pre_in = self._model_input(jnp.asarray(prompt, jnp.int32)[None, :])
+    def _prompt_input(self, prompt: np.ndarray):
+        return self._model_input(jnp.asarray(prompt, jnp.int32)[None, :])
+
+    def _prefill_request(self, params, uid: int, pre_in, n_tokens: int):
+        """Prefill one prompt input on the engine's compiled step; on the
+        paged path first bind pages for ``n_tokens`` tokens under ``uid``
+        (the caller frees them)."""
         if self.kv_pool is None:
             return self._prefill(params, pre_in)
-        page_ids = self.kv_pool.alloc(uid, len(prompt) + n_new)
+        page_ids = self.kv_pool.alloc(uid, n_tokens)
         cache0 = self._kvc.model_assign_sequence(self.kv_cache, 0, page_ids)
         return self._prefill_paged(params, pre_in, cache0)
 
@@ -590,8 +615,9 @@ class ServeEngine:
             rows.append(np.asarray(row[:self.cfg.vocab_size], np.float32))
 
         try:
-            logits, cache = self._prefill_request(params, uid, prompt,
-                                                  len(tokens) + 1)
+            logits, cache = self._prefill_request(
+                params, uid, self._prompt_input(prompt),
+                len(prompt) + len(tokens) + 1)
             keep(logits)
             for i, tok in enumerate(tokens):
                 logits, cache = self._decode(
@@ -610,19 +636,27 @@ class ServeEngine:
         ledger = get_ledger()
         plan = active_fault_plan()
         t_att = time.perf_counter()
-        with span("serve.prefill", uid=req.uid, length=len(req.prompt),
+        uid = req.uid
+        with span("serve.prefill", uid=uid, length=len(req.prompt),
                   paged=paged), ledger.step("prefill"):
-            logits, cache = self._prefill_request(
-                params, req.uid, req.prompt, req.max_new_tokens)
-            self._ensure_finite(logits)
-            nxt = self._sample(logits, req.temperature)
+            with span("serve.input", uid=uid):
+                pre_in = self._prompt_input(req.prompt)
+                h["put_token"].inc()
+            with span("serve.step", uid=uid):
+                logits, cache = self._prefill_request(
+                    params, uid, pre_in,
+                    len(req.prompt) + req.max_new_tokens)
+            with span("serve.finite", uid=uid):
+                self._ensure_finite(logits)
+            with span("serve.sample", uid=uid):
+                nxt = self._sample(logits, req.temperature)
         t_first = time.perf_counter()
         h["ttft"].observe(t_first - t_att)
         h["prefill_s"].inc(t_first - t_att)
         req.generated.append(nxt)
         h["tokens"].inc()
         pos = len(req.prompt)
-        with span("serve.decode", uid=req.uid,
+        with span("serve.decode", uid=uid,
                   tokens=req.max_new_tokens - 1):
             for _ in range(req.max_new_tokens - 1):
                 if deadline_t is not None \
@@ -636,16 +670,23 @@ class ServeEngine:
                     time.sleep(fault.slow_s)
                 if fault is not None and fault.transient:
                     raise TransientServeError(
-                        f"injected transient failure (request {req.uid})")
-                step_in = self._model_input(jnp.full((1, 1), nxt,
-                                                     jnp.int32))
+                        f"injected transient failure (request {uid})")
+                with span("serve.input", uid=uid):
+                    step_in = self._model_input(jnp.full((1, 1), nxt,
+                                                         jnp.int32))
+                    h["put_token"].inc()
+                    step_pos = jnp.int32(pos)
+                    h["put_pos"].inc()
                 with ledger.step("decode"):
-                    logits, cache = self._decode(
-                        params, step_in, cache, jnp.int32(pos))
+                    with span("serve.step", uid=uid):
+                        logits, cache = self._decode(
+                            params, step_in, cache, step_pos)
                     if fault is not None and fault.nan:
                         logits = jnp.full_like(logits, jnp.nan)
-                    self._ensure_finite(logits)
-                    nxt = self._sample(logits, req.temperature)
+                    with span("serve.finite", uid=uid):
+                        self._ensure_finite(logits)
+                    with span("serve.sample", uid=uid):
+                        nxt = self._sample(logits, req.temperature)
                 dt = time.perf_counter() - t_tok
                 h["tpot"].observe(dt)
                 h["decode_s"].inc(dt)

@@ -22,7 +22,7 @@ from repro.obs import (enable_ledger, get_ledger, get_metrics, read_trace,
                        span, tracing_enabled)
 from repro.obs import trace as trace_mod
 from repro.obs.metrics import Histogram
-from repro.obs.trace import disable_tracing, enable_tracing, instant
+from repro.obs.trace import disable_tracing, enable_tracing
 from repro.tuning import get_registry
 
 
@@ -123,19 +123,17 @@ def test_trace_roundtrip_and_nesting(tmp_path):
     with span("outer", phase="test"):
         with span("inner", i=0):
             pass
-        instant("tick", note="x")
     disable_tracing()
     assert not tracing_enabled()
 
     events = read_trace(path)
     by_name = {e["name"]: e for e in events}
-    assert set(by_name) == {"outer", "inner", "tick"}
+    assert set(by_name) == {"outer", "inner"}
     for e in events:
         assert e["cat"] == "repro"
         assert {"name", "ph", "ts", "pid", "tid"} <= set(e)
     inner, outer = by_name["inner"], by_name["outer"]
     assert inner["ph"] == outer["ph"] == "X"
-    assert by_name["tick"]["ph"] == "i"
     assert outer["args"] == {"phase": "test"}
     # Nesting is interval containment on one tid (how Perfetto rebuilds
     # the flame graph from "X" events).
@@ -146,6 +144,133 @@ def test_trace_roundtrip_and_nesting(tmp_path):
     import json
     text = open(path).read().rstrip().rstrip(",")
     assert len(json.loads(text + "\n]")) == len(events)
+
+
+@pytest.mark.parametrize("write", ["flush", "disable", "exit"])
+def test_spans_are_held_until_written(tmp_path, write):
+    """Spans stay in memory: nothing reaches the file before flush(),
+    and every span does after flush(), disable_tracing() or the
+    process's exit."""
+    path = tmp_path / "trace.jsonl"
+    if write == "exit":
+        import subprocess
+        import sys
+
+        code = ("from repro.obs import enable_tracing, span\n"
+                f"enable_tracing({str(path)!r})\n"
+                "for i in range(3):\n"
+                "    with span('tick', i=i):\n"
+                "        pass\n")
+        subprocess.run([sys.executable, "-c", code], check=True)
+    else:
+        enable_tracing(str(path))
+        for i in range(3):
+            with span("tick", i=i):
+                pass
+        assert read_trace(str(path)) == []
+        (trace_mod.flush if write == "flush" else disable_tracing)()
+    events = read_trace(str(path))
+    assert [e["args"]["i"] for e in events] == [0, 1, 2]
+
+
+def test_concurrent_spans_and_flushes_lose_nothing(tmp_path, monkeypatch):
+    """Threads record while others flush, with the buffer limit low so
+    that recording starts writer threads too: every span reaches the
+    file once."""
+    import sys
+    import threading
+
+    monkeypatch.setattr(trace_mod, "MAX_BUFFERED", 50)
+    path = str(tmp_path / "trace.jsonl")
+    enable_tracing(path)
+    n_threads, n_spans = 8, 400
+    stop = threading.Event()
+
+    def record(t):
+        for i in range(n_spans):
+            with span("tick", t=t, i=i):
+                pass
+
+    def flusher():
+        while not stop.is_set():
+            trace_mod.flush()
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        workers = [threading.Thread(target=record, args=(t,))
+                   for t in range(n_threads)]
+        flushers = [threading.Thread(target=flusher) for _ in range(2)]
+        for th in workers + flushers:
+            th.start()
+        for th in workers:
+            th.join(timeout=60)
+        stop.set()
+        for th in flushers:
+            th.join(timeout=60)
+        assert not any(th.is_alive() for th in workers + flushers)
+    finally:
+        sys.setswitchinterval(interval)
+    disable_tracing()
+    got = sorted((e["args"]["t"], e["args"]["i"]) for e in read_trace(path))
+    assert got == [(t, i) for t in range(n_threads) for i in range(n_spans)]
+
+
+def test_a_span_past_the_limit_does_not_wait_for_the_write(tmp_path,
+                                                            monkeypatch):
+    """Crossing MAX_BUFFERED hands the write to another thread: the span
+    that crossed it returns while that write is still blocked."""
+    import threading
+    import time
+
+    monkeypatch.setattr(trace_mod, "MAX_BUFFERED", 4)
+    path = str(tmp_path / "trace.jsonl")
+    enable_tracing(path)
+    tracer = trace_mod._get_tracer()
+    release, writers = threading.Event(), []
+
+    class BlockedFile:
+        def __init__(self, f):
+            self.f, self.closed = f, False
+
+        def write(self, text):
+            writers.append(threading.current_thread())
+            assert release.wait(30)
+            return self.f.write(text)
+
+        def flush(self):
+            self.f.flush()
+
+        def close(self):
+            self.closed = True
+            self.f.close()
+
+    tracer._f = BlockedFile(tracer._f)
+    for i in range(12):
+        with span("tick", i=i):
+            pass
+    deadline = time.monotonic() + 30
+    while not writers and time.monotonic() < deadline:
+        time.sleep(0.01)
+    assert writers and threading.current_thread() not in writers
+    # Every span above returned with the writer still blocked.
+    assert not release.is_set()
+    release.set()
+    disable_tracing()
+    assert [e["args"]["i"] for e in read_trace(path)] == list(range(12))
+
+
+def test_kernel_scope_names_ops_and_nests():
+    @trace_mod.kernel_scope("outer_k")
+    def f(x, depth):
+        return f(x * 2, depth - 1) if depth else x + 1
+
+    text = jax.jit(lambda x: f(x, 1)).lower(
+        jnp.ones(4)).as_text(debug_info=True)
+    assert "outer_k/outer_k/add" in text
+    assert "outer_k/mul" in text
+    # The name stack is restored after each call.
+    assert "outer_k/outer_k/outer_k" not in text
 
 
 # ---------------------------------------------------------------------------
@@ -308,3 +433,74 @@ def test_serve_engine_metrics_e2e():
                    "serve.tokens_per_second", "serve.gemm_plan_total",
                    "ledger.prefill", "ledger.decode", "model_error"):
         assert needle in report, needle
+
+
+def _serve_small(new_tokens, *, paged=False, check_finite=True):
+    from repro.configs import get_reduced
+    from repro.models import model as M
+    from repro.serve.engine import Request, ServeEngine
+
+    cfg = get_reduced("stablelm-1.6b")
+    params = M.init_params(cfg, jax.random.PRNGKey(0))
+    eng = ServeEngine(params, cfg, batch_size=1, max_len=24,
+                      warmup_gemms=False, paged_kv=paged,
+                      check_finite=check_finite)
+    r = np.random.RandomState(0)
+    for uid, n_new in enumerate(new_tokens):
+        eng.submit(Request(uid=uid, prompt=r.randint(0, cfg.vocab_size, 6),
+                           max_new_tokens=n_new))
+    return eng.run()
+
+
+PHASES = ("serve.input", "serve.step", "serve.finite", "serve.sample")
+
+
+@pytest.mark.parametrize("paged", [False, True], ids=["slab", "paged"])
+def test_serve_spans_one_per_phase_per_token(tmp_path, paged):
+    path = str(tmp_path / "trace.jsonl")
+    enable_tracing(path)
+    new_tokens = [4, 3]
+    done = _serve_small(new_tokens, paged=paged)
+    disable_tracing()
+    assert all(r.status == "done" for r in done.values())
+    events = read_trace(path)
+    for uid, n_new in enumerate(new_tokens):
+        mine = [e for e in events if e.get("args", {}).get("uid") == uid]
+        (prefill,) = [e for e in mine if e["name"] == "serve.prefill"]
+        (decode,) = [e for e in mine if e["name"] == "serve.decode"]
+
+        def inside(e, outer):
+            return (e["tid"] == outer["tid"] and outer["ts"] <= e["ts"]
+                    and e["ts"] + e["dur"] <= outer["ts"] + outer["dur"])
+
+        for name in PHASES:
+            phase = [e for e in mine if e["name"] == name]
+            assert len(phase) == n_new, (name, len(phase))
+            assert sum(inside(e, prefill) for e in phase) == 1
+            assert sum(inside(e, decode) for e in phase) == n_new - 1
+        # Within one token the phases run in order.
+        in_decode = sorted((e for e in mine if e["name"] in PHASES
+                            and inside(e, decode)), key=lambda e: e["ts"])
+        assert [e["name"] for e in in_decode] == list(PHASES) * (n_new - 1)
+
+
+@pytest.mark.parametrize("check_finite", [True, False],
+                         ids=["checked", "unchecked"])
+def test_host_sync_and_put_counters(check_finite):
+    new_tokens = [4, 3]
+    _serve_small(new_tokens, check_finite=check_finite)
+    mets = get_metrics().snapshot()
+    n_req, n_tok = len(new_tokens), sum(new_tokens)
+    n_decode = n_tok - n_req
+    syncs = mets["serve.host_syncs_total"]["labels"]
+    puts = mets["serve.host_puts_total"]["labels"]
+    # Every token (prefill's and each decode step's) reads its sample
+    # back, and its finite check when one is made.
+    assert syncs.get("at=sample") == n_tok
+    assert syncs.get("at=finite", 0) == (n_tok if check_finite else 0)
+    # Prefill puts the prompt; each decode step puts a token and a pos.
+    assert puts == {"what=token": n_req + n_decode, "what=pos": n_decode}
+    if check_finite:
+        assert mets["serve.host_syncs_total"]["value"] == 2 * n_tok
+        per_decode = (puts["what=token"] + puts["what=pos"] - n_req)
+        assert per_decode == 2 * n_decode

@@ -102,3 +102,33 @@ def test_compile_cache_dir_rule(monkeypatch, tmp_path):
         assert (tmp_path / "fixed").is_dir()
     finally:
         jax.config.update("jax_compilation_cache_dir", prev)
+
+
+@pytest.mark.parametrize("paged", [False, True], ids=["slab", "paged"])
+def test_decode_step_is_named_and_scoped(paged):
+    """The engine's decode step compiles as ``jit_serve_decode``, and its
+    ops carry the kernel-family scopes a device trace reads in ``tf_op``:
+    ``gemm`` (projections), ``attn`` (attention core) and ``kv_write``
+    (the cache update), on the slab and the paged cache alike."""
+    import re
+
+    cfg = get_reduced("stablelm-1.6b")
+    params = M.init_params(cfg, jax.random.PRNGKey(0))
+    eng = ServeEngine(params, cfg, batch_size=1, max_len=32,
+                      warmup_gemms=False, paged_kv=paged)
+    if paged:
+        cache = eng.kv_cache
+    else:
+        _, cache = eng._prefill(params,
+                                {"tokens": jnp.zeros((1, 8), jnp.int32)})
+    hlo = eng._decode.lower(
+        params, {"tokens": jnp.zeros((1, 1), jnp.int32)}, cache,
+        jnp.int32(8)).compile().as_text()
+    assert hlo.startswith("HloModule jit_serve_decode")
+    op_names = re.findall(r'op_name="([^"]*)"', hlo)
+    # (Ops of reduction sub-computations carry no path at all.)
+    paths = [n for n in op_names if n.startswith("jit(")]
+    assert paths and all(n.startswith("jit(serve_decode)/")
+                         for n in paths)
+    for scope in ("gemm", "attn", "kv_write"):
+        assert any(f"/{scope}/" in n for n in op_names), scope
