@@ -27,7 +27,7 @@ import dataclasses
 import functools
 import time
 import warnings
-from typing import Deque, Dict, List, Optional, Tuple
+from typing import Any, Deque, Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -54,9 +54,56 @@ _DEGRADED_DESC = ("Quant degradations, by from/to level (per-request "
 _REJECTED_DESC = "Requests rejected/shed at admission, by policy"
 _FALLBACK_DESC = ("Kernel-path GEMM dispatch failures re-dispatched on "
                   "the XLA oracle path, by dispatch stage")
-_SYNCS_DESC = "Device-to-host reads in the token loop, by where (at)"
+_SYNCS_DESC = ("Device-to-host reads in the token loop, by where (at): "
+               "one per output token, at=sample (its token and finite "
+               "flag)")
 _PUTS_DESC = ("Host-to-device inputs made for the model steps, by what "
-              "they carry")
+              "they carry: the prompt with its temperature, once per "
+              "serve attempt; decode steps take the previous step's "
+              "device outputs")
+
+
+class Step(NamedTuple):
+    """What a jitted serve step returns, all on the device.  The next
+    decode step takes ``token``, ``cache``, ``pos`` and ``key`` as they
+    are; the host reads ``token`` and ``finite`` once (``_sample``)."""
+    logits: jax.Array    # (1, L, [n_codebooks,] vocab_padded) fp32
+    cache: Any
+    token: jax.Array     # (1, 1) int32, sampled from the last row
+    finite: jax.Array    # () bool, that row all finite
+    pos: jax.Array       # () int32, the position of ``token``
+    key: jax.Array       # the engine key after ``token``'s draw
+
+
+def _token_input(cfg: ModelConfig, toks: jax.Array, table):
+    """Model input for ``(1, L)`` token ids: the ids, or their rows of
+    the demo embedding table for an embeds-frontend config."""
+    if cfg.frontend == "tokens":
+        return {"tokens": toks}
+    return {"embeds": table[toks]}
+
+
+def _step(cfg: ModelConfig, logits: jax.Array, cache, pos: jax.Array,
+          key: jax.Array, temperature: jax.Array) -> Step:
+    """A step's :class:`Step`, traced inside it: the finite flag over the
+    last row of ``logits`` (every codebook), and the token drawn from
+    that row (codebook 0): greedy at ``temperature <= 0``, else one split
+    of ``key`` and a categorical draw at ``temperature``."""
+    row = logits[0, -1, ..., :cfg.vocab_size]
+    finite = jnp.all(jnp.isfinite(row))
+    if cfg.n_codebooks > 1:
+        row = row[0]
+
+    def greedy(key):
+        return jnp.argmax(row).astype(jnp.int32), key
+
+    def draw(key):
+        key, sub = jax.random.split(key)
+        tok = jax.random.categorical(sub, row / temperature)
+        return tok.astype(jnp.int32), key
+
+    tok, key = jax.lax.cond(temperature > 0, draw, greedy, key)
+    return Step(logits, cache, tok.reshape(1, 1), finite, pos, key)
 
 
 class NonFiniteLogits(RuntimeError):
@@ -102,7 +149,8 @@ class Request:
 
 
 class ServeEngine:
-    """Single-host batched engine (the dry-run lowers its jitted steps)."""
+    """Single-host engine that serves one request at a time at batch 1
+    (ROADMAP S4 brings batching)."""
 
     def __init__(self, params, cfg: ModelConfig, *, batch_size: int,
                  max_len: int, seed: int = 0, warmup_gemms: bool = True,
@@ -206,12 +254,20 @@ class ServeEngine:
             plan_counter.labels(source=src).inc()
         # Named steps: the compiled modules read ``jit_serve_prefill`` /
         # ``jit_serve_decode`` in a device trace, and every op's name
-        # path starts with ``jit(serve_decode)``.
-        def serve_prefill(p, b):
-            return M.prefill(p, b, cfg, max_len=max_len)
+        # path starts with ``jit(serve_decode)``.  Each step also samples
+        # its next token and checks that row for NaN/Inf (``Step``), so
+        # the next decode step starts from device values alone.
+        # ``temperature`` is traced: one program serves every request.
+        def serve_prefill(p, toks, key, temperature, table):
+            logits, cache = M.prefill(p, _token_input(cfg, toks, table),
+                                      cfg, max_len=max_len)
+            return _step(cfg, logits, cache, jnp.int32(toks.shape[1]), key,
+                         temperature)
 
-        def serve_decode(p, t, c, s):
-            return M.decode_step(p, t, c, s, cfg)
+        def serve_decode(p, tok, c, pos, key, temperature, table):
+            logits, cache = M.decode_step(
+                p, _token_input(cfg, tok, table), c, pos, cfg)
+            return _step(cfg, logits, cache, pos + 1, key, temperature)
 
         self._prefill = jax.jit(serve_prefill)
         self._decode = jax.jit(serve_decode)
@@ -258,8 +314,12 @@ class ServeEngine:
                 cfg, 1, n_pages=self.kv_pool.n_pages,
                 page_size=kv_page_size, max_pages=self.kv_max_pages_per_seq)
 
-            def serve_prefill_paged(p, b, c):
-                return M.prefill(p, b, cfg, max_len=max_len, cache=c)
+            def serve_prefill_paged(p, toks, c, key, temperature, table):
+                logits, cache = M.prefill(
+                    p, _token_input(cfg, toks, table), cfg,
+                    max_len=max_len, cache=c)
+                return _step(cfg, logits, cache, jnp.int32(toks.shape[1]),
+                             key, temperature)
 
             self._prefill_paged = jax.jit(serve_prefill_paged)
         self.base_level = ("w8a8" if self.w8a8
@@ -270,11 +330,12 @@ class ServeEngine:
         self._submit_t: Dict[int, float] = {}
 
     @functools.cached_property
-    def _sample_table(self) -> jax.Array:
-        """Deterministic demo embedding table for embeds-frontend configs
-        (seed 0, the historical convention) — built once and shared by
-        calibration sampling and the serve loop; ``run()`` used to
-        rebuild this (vocab, d) randn per request."""
+    def _table(self) -> Optional[jax.Array]:
+        """Deterministic demo embedding table of an embeds-frontend config
+        (seed 0, the historical convention), built once and passed to
+        every step; None for a tokens frontend."""
+        if self.cfg.frontend == "tokens":
+            return None
         return jnp.asarray(
             np.random.RandomState(0).randn(self.cfg.vocab_size,
                                            self.cfg.d_model) * 0.02,
@@ -282,8 +343,9 @@ class ServeEngine:
 
     def _sample_inputs(self, rng: np.random.RandomState, length: int):
         """One prefill input of sample traffic (tokens or embeds)."""
-        return self._model_input(jnp.asarray(
-            rng.randint(0, self.cfg.vocab_size, (1, length)), jnp.int32))
+        return _token_input(self.cfg, jnp.asarray(
+            rng.randint(0, self.cfg.vocab_size, (1, length)), jnp.int32),
+            self._table)
 
     def _calibrate_activations(self, n_batches: int):
         """The classic post-training static calibration loop: forward a
@@ -388,38 +450,31 @@ class ServeEngine:
         self._submit_t[req.uid] = time.perf_counter()
         return True
 
-    def _sample(self, logits: jax.Array, temperature: float) -> int:
-        logits = logits[..., :self.cfg.vocab_size]
-        if self.cfg.n_codebooks > 1:
-            logits = logits[..., 0, :]  # report codebook 0 for the demo
-        if temperature <= 0:
-            tok = int(jnp.argmax(logits[0, -1]))
-        else:
-            self.key, sub = jax.random.split(self.key)
-            tok = int(jax.random.categorical(sub,
-                                             logits[0, -1] / temperature))
+    def _sample(self, step: Step, temperature: float) -> int:
+        """The token ``step`` sampled at ``temperature``: the one place a
+        served token reaches the host, in one read with the step's
+        finite flag.  Raises :class:`NonFiniteLogits` when the sampled
+        row is poisoned and ``check_finite`` is on.  A drawn token
+        consumed one split of the engine's key, which the next request
+        starts from; a greedy step hands its key back unchanged.
+        ``temperature`` is unused here (the step sampled already) and is
+        kept because the benchmark harness's tests patch this method
+        with that signature."""
+        tok, finite = jax.device_get((step.token, step.finite))
         self._h["sync_sample"].inc()
-        return tok
-
-    def _ensure_finite(self, logits: jax.Array) -> None:
-        """Raise :class:`NonFiniteLogits` when the sampled row is poisoned
-        (one cheap reduction and one sync per token)."""
-        if not self.check_finite:
-            return
-        finite = bool(jnp.all(jnp.isfinite(
-            logits[0, -1, ..., :self.cfg.vocab_size])))
-        self._h["sync_finite"].inc()
-        if not finite:
+        if self.check_finite and not finite:
             raise NonFiniteLogits("non-finite logits in sampled row")
+        self.key = step.key
+        return int(tok[0, 0])
 
     # -- the serve loop -----------------------------------------------------
 
     def run(self) -> Dict[int, Request]:
-        """Serve everything in the queue (batch-of-1 prefill, batched
-        decode loop per request group of equal prompt length).
+        """Serve everything in the queue, one request at a time: a
+        prefill at batch 1, then a decode loop at batch 1.
 
-        Fully instrumented: queue wait, TTFT (dequeue to first sampled
-        token — prefill plus one sample), per-output-token decode latency
+        Fully instrumented: queue wait, TTFT (dequeue to first token on
+        the host), the interval between a request's output tokens
         (TPOT), and the prefill/decode wall split land in the metrics
         registry; each phase runs under a trace span and a GEMM-ledger
         step, so ``metrics_report()`` can state achieved bytes/s against
@@ -438,13 +493,15 @@ class ServeEngine:
                 "serve.ttft_seconds", "Dequeue to first sampled token"),
             "tpot": metrics.histogram(
                 "serve.tpot_seconds",
-                "Per-output-token decode latency (decode step + sample)"),
+                "Interval between a request's consecutive output tokens "
+                "reaching the host (each decode step is dispatched "
+                "before the previous token is read)"),
             "prefill_s": metrics.counter(
                 "serve.prefill_seconds_total",
-                "Wall time in prefill+sample"),
+                "Wall time from dequeue to the first token on the host"),
             "decode_s": metrics.counter(
                 "serve.decode_seconds_total",
-                "Wall time in the decode loop"),
+                "Wall time from the first token to the last on the host"),
             "tokens": metrics.counter(
                 "serve.tokens_generated_total", "Sampled output tokens"),
             "n_requests": metrics.counter(
@@ -461,10 +518,8 @@ class ServeEngine:
         }
         syncs = metrics.counter("serve.host_syncs_total", _SYNCS_DESC)
         puts = metrics.counter("serve.host_puts_total", _PUTS_DESC)
-        self._h.update(sync_finite=syncs.labels(at="finite"),
-                       sync_sample=syncs.labels(at="sample"),
-                       put_token=puts.labels(what="token"),
-                       put_pos=puts.labels(what="pos"))
+        self._h.update(sync_sample=syncs.labels(at="sample"),
+                       put_prompt=puts.labels(what="prompt"))
         tokens = self._h["tokens"]
         t_run = time.perf_counter()
         while self.queue:
@@ -574,25 +629,25 @@ class ServeEngine:
         finally:
             self.kv_pool.free(req.uid)
 
-    def _model_input(self, toks: jax.Array) -> Dict[str, jax.Array]:
-        """Prefill/decode input for ``(1, L)`` token ids (tokens or the
-        demo embeddings of an embeds-frontend config)."""
-        if self.cfg.frontend == "tokens":
-            return {"tokens": toks}
-        return {"embeds": self._sample_table[toks]}
-
-    def _prompt_input(self, prompt: np.ndarray):
-        return self._model_input(jnp.asarray(prompt, jnp.int32)[None, :])
-
-    def _prefill_request(self, params, uid: int, pre_in, n_tokens: int):
-        """Prefill one prompt input on the engine's compiled step; on the
-        paged path first bind pages for ``n_tokens`` tokens under ``uid``
-        (the caller frees them)."""
+    def _prefill_request(self, params, uid: int, prompt: jax.Array,
+                         temperature: jax.Array, n_tokens: int) -> Step:
+        """Prefill one ``(1, L)`` prompt on the engine's compiled step; on
+        the paged path first bind pages for ``n_tokens`` tokens under
+        ``uid`` (the caller frees them)."""
         if self.kv_pool is None:
-            return self._prefill(params, pre_in)
+            return self._prefill(params, prompt, self.key, temperature,
+                                 self._table)
         page_ids = self.kv_pool.alloc(uid, n_tokens)
         cache0 = self._kvc.model_assign_sequence(self.kv_cache, 0, page_ids)
-        return self._prefill_paged(params, pre_in, cache0)
+        return self._prefill_paged(params, prompt, cache0, self.key,
+                                   temperature, self._table)
+
+    def _decode_after(self, params, step: Step,
+                      temperature: jax.Array) -> Step:
+        """Dispatch the decode step that takes ``step``'s token, from
+        ``step``'s device outputs alone."""
+        return self._decode(params, step.token, step.cache, step.pos,
+                            step.key, temperature, self._table)
 
     def teacher_forced_logits(self, prompt: np.ndarray,
                               tokens) -> np.ndarray:
@@ -606,25 +661,26 @@ class ServeEngine:
         """
         params = self._params_for(self.base_level)
         uid = -1 - len(self.done)       # never a request uid
+        greedy = jnp.float32(0)
         rows = []
 
-        def keep(logits):
-            row = logits[0, -1]
+        def keep(step):
+            row = step.logits[0, -1]
             if self.cfg.n_codebooks > 1:
                 row = row[0]
             rows.append(np.asarray(row[:self.cfg.vocab_size], np.float32))
 
         try:
-            logits, cache = self._prefill_request(
-                params, uid, self._prompt_input(prompt),
-                len(prompt) + len(tokens) + 1)
-            keep(logits)
-            for i, tok in enumerate(tokens):
-                logits, cache = self._decode(
-                    params, self._model_input(jnp.full((1, 1), tok,
-                                                       jnp.int32)),
-                    cache, jnp.int32(len(prompt) + i))
-                keep(logits)
+            step = self._prefill_request(
+                params, uid, jnp.asarray(prompt, jnp.int32)[None, :],
+                greedy, len(prompt) + len(tokens) + 1)
+            keep(step)
+            for tok in tokens:
+                step = self._decode_after(
+                    params, step._replace(token=jnp.full((1, 1), tok,
+                                                         jnp.int32)),
+                    greedy)
+                keep(step)
         finally:
             if self.kv_pool is not None:
                 self.kv_pool.free(uid)
@@ -632,67 +688,65 @@ class ServeEngine:
 
     def _serve_attempt(self, req: Request, params,
                        deadline_t: Optional[float], *, paged: bool) -> None:
+        """Prefill, then ``max_new_tokens - 1`` decode steps, each
+        dispatched from the previous step's device outputs before the
+        host reads that step's token: the device runs step n + 1 while
+        the host reads, checks and appends token n."""
         h = self._h
         ledger = get_ledger()
         plan = active_fault_plan()
-        t_att = time.perf_counter()
         uid = req.uid
+        t_att = t_last = time.perf_counter()
+
+        def take(step: Step) -> None:
+            nonlocal t_last
+            with span("serve.sample", uid=uid):
+                tok = self._sample(step, req.temperature)
+            now = time.perf_counter()
+            if req.generated:
+                h["tpot"].observe(now - t_last)
+                h["decode_s"].inc(now - t_last)
+            else:
+                h["ttft"].observe(now - t_att)
+                h["prefill_s"].inc(now - t_att)
+            t_last = now
+            h["tokens"].inc()
+            req.generated.append(tok)
+
         with span("serve.prefill", uid=uid, length=len(req.prompt),
                   paged=paged), ledger.step("prefill"):
             with span("serve.input", uid=uid):
-                pre_in = self._prompt_input(req.prompt)
-                h["put_token"].inc()
+                prompt, temperature = jax.device_put((
+                    np.asarray(req.prompt, np.int32)[None, :],
+                    np.float32(req.temperature)))
+                h["put_prompt"].inc()
             with span("serve.step", uid=uid):
-                logits, cache = self._prefill_request(
-                    params, uid, pre_in,
+                step = self._prefill_request(
+                    params, uid, prompt, temperature,
                     len(req.prompt) + req.max_new_tokens)
-            with span("serve.finite", uid=uid):
-                self._ensure_finite(logits)
-            with span("serve.sample", uid=uid):
-                nxt = self._sample(logits, req.temperature)
-        t_first = time.perf_counter()
-        h["ttft"].observe(t_first - t_att)
-        h["prefill_s"].inc(t_first - t_att)
-        req.generated.append(nxt)
-        h["tokens"].inc()
-        pos = len(req.prompt)
         with span("serve.decode", uid=uid,
                   tokens=req.max_new_tokens - 1):
             for _ in range(req.max_new_tokens - 1):
                 if deadline_t is not None \
                         and time.perf_counter() > deadline_t:
+                    take(step)          # computed before the deadline
                     raise DeadlineExceeded(
                         f"decode deadline {req.deadline_s}s exceeded "
                         f"after {len(req.generated)} tokens")
-                t_tok = time.perf_counter()
                 fault = plan.decode_fault() if plan is not None else None
                 if fault is not None and fault.slow_s:
                     time.sleep(fault.slow_s)
                 if fault is not None and fault.transient:
                     raise TransientServeError(
                         f"injected transient failure (request {uid})")
-                with span("serve.input", uid=uid):
-                    step_in = self._model_input(jnp.full((1, 1), nxt,
-                                                         jnp.int32))
-                    h["put_token"].inc()
-                    step_pos = jnp.int32(pos)
-                    h["put_pos"].inc()
                 with ledger.step("decode"):
                     with span("serve.step", uid=uid):
-                        logits, cache = self._decode(
-                            params, step_in, cache, step_pos)
+                        nxt = self._decode_after(params, step, temperature)
                     if fault is not None and fault.nan:
-                        logits = jnp.full_like(logits, jnp.nan)
-                    with span("serve.finite", uid=uid):
-                        self._ensure_finite(logits)
-                    with span("serve.sample", uid=uid):
-                        nxt = self._sample(logits, req.temperature)
-                dt = time.perf_counter() - t_tok
-                h["tpot"].observe(dt)
-                h["decode_s"].inc(dt)
-                h["tokens"].inc()
-                req.generated.append(nxt)
-                pos += 1
+                        nxt = nxt._replace(finite=np.False_)
+                    take(step)
+                step = nxt
+            take(step)
 
     def metrics_snapshot(self) -> Dict[str, dict]:
         """JSON-ready view of everything observed: the metrics registry

@@ -452,11 +452,14 @@ def _serve_small(new_tokens, *, paged=False, check_finite=True):
     return eng.run()
 
 
-PHASES = ("serve.input", "serve.step", "serve.finite", "serve.sample")
+PHASES = ("serve.step", "serve.sample")
 
 
 @pytest.mark.parametrize("paged", [False, True], ids=["slab", "paged"])
 def test_serve_spans_one_per_phase_per_token(tmp_path, paged):
+    """The prefill span holds the prompt's put and the prefill dispatch;
+    the decode span holds each decode dispatch, each followed by the
+    read of the token before it, then the last token's read."""
     path = str(tmp_path / "trace.jsonl")
     enable_tracing(path)
     new_tokens = [4, 3]
@@ -473,15 +476,22 @@ def test_serve_spans_one_per_phase_per_token(tmp_path, paged):
             return (e["tid"] == outer["tid"] and outer["ts"] <= e["ts"]
                     and e["ts"] + e["dur"] <= outer["ts"] + outer["dur"])
 
-        for name in PHASES:
-            phase = [e for e in mine if e["name"] == name]
-            assert len(phase) == n_new, (name, len(phase))
-            assert sum(inside(e, prefill) for e in phase) == 1
-            assert sum(inside(e, decode) for e in phase) == n_new - 1
-        # Within one token the phases run in order.
+        (put,) = [e for e in mine if e["name"] == "serve.input"]
+        assert inside(put, prefill)
+        assert not [e for e in mine if e["name"] == "serve.finite"]
+        steps = [e for e in mine if e["name"] == "serve.step"]
+        assert len(steps) == n_new
+        assert sum(inside(e, prefill) for e in steps) == 1
+        assert sum(inside(e, decode) for e in steps) == n_new - 1
+        samples = [e for e in mine if e["name"] == "serve.sample"]
+        assert len(samples) == n_new
+        assert all(inside(e, decode) for e in samples)
+        # Within the decode span each dispatch precedes the read of the
+        # token before it.
         in_decode = sorted((e for e in mine if e["name"] in PHASES
                             and inside(e, decode)), key=lambda e: e["ts"])
-        assert [e["name"] for e in in_decode] == list(PHASES) * (n_new - 1)
+        assert [e["name"] for e in in_decode] == \
+            list(PHASES) * (n_new - 1) + ["serve.sample"]
 
 
 @pytest.mark.parametrize("check_finite", [True, False],
@@ -491,16 +501,11 @@ def test_host_sync_and_put_counters(check_finite):
     _serve_small(new_tokens, check_finite=check_finite)
     mets = get_metrics().snapshot()
     n_req, n_tok = len(new_tokens), sum(new_tokens)
-    n_decode = n_tok - n_req
     syncs = mets["serve.host_syncs_total"]["labels"]
     puts = mets["serve.host_puts_total"]["labels"]
-    # Every token (prefill's and each decode step's) reads its sample
-    # back, and its finite check when one is made.
-    assert syncs.get("at=sample") == n_tok
-    assert syncs.get("at=finite", 0) == (n_tok if check_finite else 0)
-    # Prefill puts the prompt; each decode step puts a token and a pos.
-    assert puts == {"what=token": n_req + n_decode, "what=pos": n_decode}
-    if check_finite:
-        assert mets["serve.host_syncs_total"]["value"] == 2 * n_tok
-        per_decode = (puts["what=token"] + puts["what=pos"] - n_req)
-        assert per_decode == 2 * n_decode
+    # Every token (prefill's and each decode step's) is read once, with
+    # its finite flag, whether or not the flag is acted on.
+    assert syncs == {"at=sample": n_tok}
+    assert mets["serve.host_syncs_total"]["value"] == n_tok
+    # Each request puts its prompt; decode steps put nothing.
+    assert puts == {"what=prompt": n_req}
