@@ -3,7 +3,8 @@ float32 reference.
 
 Once the window has closed and the program is freed, a sample of the
 finished requests, drawn from the seed and always holding the longest,
-is run through :mod:`bench.reference.dense` over its prompt and served
+is run through the config's block (``bench/reference/<block>.py``:
+its ``plain_weights`` and ``logits_at``) over its prompt and served
 tokens.  The number compared is the widest gap by which a served token's
 logit lies below the reference's best logit at that position: greedy
 decoding of a correct program picks the reference's first choice up to
@@ -19,6 +20,8 @@ from __future__ import annotations
 from typing import Dict, List, Sequence, Tuple
 
 import numpy as np
+
+from bench.reference.common import served_rows, widest_gap
 
 Served = Dict[int, Tuple[np.ndarray, List[int]]]
 SAMPLE_SALT = 0x5EED
@@ -38,28 +41,26 @@ def sample(served: Served, n: int, seed: int) -> List[int]:
     return [longest] + sorted(picked)
 
 
-def widest_gaps(spec, seed: int, served: Served, uids: Sequence[int],
-                control: bool = False) -> dict:
-    """Widest gap over the sampled requests; with ``control`` the same
-    for the fp8 control's own first choices."""
+def widest_gaps(block, spec, seed: int, served: Served,
+                uids: Sequence[int], control: bool = False) -> dict:
+    """Widest gap over the sampled requests, by ``block``'s reference;
+    with ``control`` the same for the fp8 control's own first
+    choices."""
     import jax
 
-    from bench.lib.weights import plain_weights
-    from bench.reference import dense
-
-    w = plain_weights(spec, seed)
+    w = block.plain_weights(spec, seed)
     worst, worst_ctl, tokens = 0.0, 0.0, 0
     with jax.default_matmul_precision("highest"):
         for u in uids:
             prompt, gen = served[u]
             seq = list(prompt) + list(gen[:-1])
-            rows = dense.served_rows(len(prompt), len(gen))
-            ref = dense.logits_at(w, spec, seq, rows)
-            worst = max(worst, dense.widest_gap(ref, gen))
+            rows = served_rows(len(prompt), len(gen))
+            ref = block.logits_at(w, spec, seq, rows)
+            worst = max(worst, widest_gap(ref, gen))
             tokens += len(gen)
             if control:
-                low = dense.logits_at(w, spec, seq, rows, precision="fp8")
-                worst_ctl = max(worst_ctl, dense.widest_gap(
+                low = block.logits_at(w, spec, seq, rows, precision="fp8")
+                worst_ctl = max(worst_ctl, widest_gap(
                     ref, np.argmax(low, axis=-1)))
     del w
     out = {"widest_gap": worst, "requests": len(uids), "tokens": tokens}

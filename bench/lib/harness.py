@@ -18,21 +18,24 @@ run's output directory for the per-layer readers.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import gc
+import hashlib
+import importlib.util
 import json
 import os
 import pathlib
 import shutil
+import sys
 import threading
 import time
-from typing import Callable, List, Optional
+import types
+from typing import Any, Callable, List, Optional, Tuple
 
 import numpy as np
 
 from . import traffic as traffic_mod
 from .trace import WINDOW
-from .spec import Spec, load_spec, program_config
-from .weights import program_weights
 
 BENCH = pathlib.Path(__file__).resolve().parents[1]
 ROOT = BENCH.parent
@@ -114,7 +117,8 @@ class RunRecord:
     """Everything a metric file may read.  Times are seconds from the
     window's start on the host clock."""
     cell: str
-    spec: Spec
+    block: types.ModuleType        # bench/reference/<block>.py
+    spec: Any                      # the block's spec of the config
     loop: str
     seconds: float
     requests: List[ReqRecord]
@@ -150,6 +154,32 @@ def find_cell(bench: dict, name: str) -> dict:
         if w["name"] == name:
             return w
     raise KeyError(f"no workload {name!r} in BENCHMARK.json")
+
+
+@functools.lru_cache(maxsize=None)
+def _load_block(path: str) -> types.ModuleType:
+    # One module name per path; the module is registered before it runs,
+    # as a dataclass in it needs.
+    name = "bench_block_" + hashlib.sha1(path.encode()).hexdigest()[:12]
+    found = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(found)
+    sys.modules[name] = mod
+    found.loader.exec_module(mod)
+    return mod
+
+
+def load_config(bench_dir: pathlib.Path, name: str
+                ) -> Tuple[dict, types.ModuleType, Any]:
+    """The file ``configs/<name>.json``, the block module that its
+    ``"block"`` key names (``reference/<block>.py``, loaded once per
+    path), and that block's spec of the file."""
+    path = bench_dir / "configs" / f"{name}.json"
+    raw = json.loads(path.read_text())
+    if "block" not in raw:
+        raise ValueError(f"{path}: no \"block\" key; it names the module "
+                         "reference/<block>.py that computes the config")
+    block = _load_block(str(bench_dir / "reference" / f"{raw['block']}.py"))
+    return raw, block, block.load_spec(raw, name)
 
 
 def setup_compile_cache(root: pathlib.Path = ROOT) -> str:
@@ -209,17 +239,17 @@ def run_cell(cell: dict, seed: int, seconds: float, t_start: float,
     from repro.obs import trace as spans_mod
     from repro.serve.engine import ServeEngine
 
-    spec = load_spec(bench_dir / "configs" / f"{cell['config']}.json")
+    raw, block, spec = load_config(bench_dir, cell["config"])
     mix = mix or traffic_mod.load_mix(
         bench_dir / "traffic" / f"{cell['traffic']}.json")
     peaks = json.loads((bench_dir / "peaks.json").read_text())
-    cfg = program_config(spec)
+    cfg = block.program_config(spec)
     out_dir = pathlib.Path(opts.out_dir) / f"{cell['name']}.{seed}"
     shutil.rmtree(out_dir, ignore_errors=True)
     out_dir.mkdir(parents=True)
 
     # -- set-up: weights, engine, one warm request per prompt length ------
-    params = program_weights(spec, seed, cfg)
+    params = block.program_weights(spec, seed, cfg)
     engine = ServeEngine(params, cfg, batch_size=mix.batch_size,
                          max_len=mix.max_len, seed=0)
     TimedRequest = timed_request_class()
@@ -385,9 +415,9 @@ def run_cell(cell: dict, seed: int, seconds: float, t_start: float,
             ev["ts"] = ev["ts"] * 1e-6 + span_t0 - t0
             ev["dur"] = ev["dur"] * 1e-6
             spans.append(ev)
-    record = RunRecord(cell=cell["name"], spec=spec, loop=mix.loop,
-                       seconds=seconds, requests=requests, spans=spans,
-                       closed_at=closed_at,
+    record = RunRecord(cell=cell["name"], block=block, spec=spec,
+                       loop=mix.loop, seconds=seconds, requests=requests,
+                       spans=spans, closed_at=closed_at,
                        peaks=peaks[jax.devices()[0].device_kind]
                        if jax.devices()[0].device_kind in peaks else {})
     if opts.trace:
@@ -412,6 +442,7 @@ def run_cell(cell: dict, seed: int, seconds: float, t_start: float,
         "lateness": lateness,
         "peak_bytes": peak_bytes,
         "record": record,
+        "config": raw,
         "served": served,
         "failed": failed,
         "out_dir": out_dir,

@@ -4,13 +4,11 @@ time is the gap between its token and the one before it, as the harness
 stamped them; the steps are those of the requests due in the window
 that finished before the profiler started, since profiling slows the
 service.  The bytes (weights once per step, K/V at the actual context)
-are counted by ``bench/lib/counts.py``.  Unlike ``model.mbu`` it does
-not count the time between requests, so the offered rate does not set
-it."""
+are counted by the config's block (``bench/reference/<block>.py``).
+Unlike ``model.mbu`` it does not count the time between requests, so
+the offered rate does not set it."""
 
 import math
-
-from bench.lib import counts
 
 
 def read(rec):
@@ -22,7 +20,7 @@ def read(rec):
         if r.due >= rec.seconds or not t or t[-1] >= cut:
             continue
         for i in range(1, len(t)):
-            nbytes += counts.decode_bytes(rec.spec, r.prompt_len + i)
+            nbytes += rec.block.decode_bytes(rec.spec, r.prompt_len + i)
             seconds += t[i] - t[i - 1]
     if not peak or not seconds:
         return None

@@ -4,7 +4,9 @@
     python3 bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 The cell is an entry of ``workloads`` in ``BENCHMARK.json``; it names a
-configuration (``bench/configs/<config>.json``) and a traffic mix
+configuration (``bench/configs/<config>.json``, whose ``"block"`` key
+names the module ``bench/reference/<block>.py`` that gives its spec,
+weights, reference and counts) and a traffic mix
 (``bench/traffic/<mix>.json``).  Set-up makes the weights from the seed,
 builds the engine and warms every prompt length of the mix; the window
 then offers the mix's load for ``--seconds``; afterwards the served
@@ -115,10 +117,10 @@ def execute(bench: dict, cell_name: str, seed: int, seconds: float,
     missing = sum(1 for r in rec.requests if r.due < seconds
                   and r.first is None and rec.loop == "open")
     failed = run["failed"] + missing
-    spec = rec.spec
-    limit = float(spec.raw["widest_gap_limit"])
+    limit = float(run["config"]["widest_gap_limit"])
     uids = check.sample(run["served"], run["mix"].check_requests, seed)
-    got = check.widest_gaps(spec, seed, run["served"], uids, control=control)
+    got = check.widest_gaps(rec.block, rec.spec, seed, run["served"], uids,
+                            control=control)
     gap = got["control_widest_gap"] if control else got["widest_gap"]
     compared = {
         "widest_gap": {"value": gap, "limit": limit},

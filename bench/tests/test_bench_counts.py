@@ -5,14 +5,15 @@ import pathlib
 
 import pytest
 
-from bench.lib import counts
-from bench.lib.spec import load_spec
+from bench.lib.harness import load_config
 
 BENCH = pathlib.Path(__file__).resolve().parents[1]
 
 
 def _spec(name):
-    return load_spec(BENCH / "configs" / f"{name}.json")
+    """The published config's spec, and its block as ``counts``."""
+    _, block, spec = load_config(BENCH, name)
+    return block, spec
 
 
 # Hand counts from the published sizes, one line per term.
@@ -42,7 +43,7 @@ DANUBE = dict(
 @pytest.mark.parametrize("name, hand", [("stablelm-2-1.6b", STABLELM),
                                         ("h2o-danube3-4b", DANUBE)])
 def test_counts_match_hand_counts(name, hand):
-    s = _spec(name)
+    counts, s = _spec(name)
     assert counts.proj_flops_per_token(s) == hand["proj_per_token"]
     assert counts.head_flops(s) == hand["head"]
     assert counts.weight_bytes(s) == hand["weight_bytes"]
@@ -63,7 +64,7 @@ def test_counts_match_hand_counts(name, hand):
 def test_stablelm_weight_bytes_are_the_published_size():
     # 1.64 B parameters in bf16, less the embedding table: 2.88 GB read
     # per step, 3.29 GB held.
-    s = _spec("stablelm-2-1.6b")
+    counts, s = _spec("stablelm-2-1.6b")
     held = counts.weight_bytes(s) + 2 * s.vocab * s.d_model
     assert abs(held / 1e9 - 3.29) < 0.01
 
@@ -88,10 +89,10 @@ def test_decode_bandwidth_share_reads_token_gaps():
     spec = importlib.util.spec_from_file_location("mbu_decode", path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    s = _spec("stablelm-2-1.6b")
+    counts, s = _spec("stablelm-2-1.6b")
     req = lambda due, times: NS(due=due, prompt_len=100,  # noqa: E731
                                 token_times=times)
-    rec = NS(spec=s, seconds=10.0, profile_started=5.0,
+    rec = NS(block=counts, spec=s, seconds=10.0, profile_started=5.0,
              peaks={"hbm_bytes_per_s": 819e9},
              requests=[req(0.0, [0.1, 0.11, 0.13]),
                        req(4.0, [4.9, 5.1]),       # ends after the profile

@@ -18,6 +18,7 @@ LIMIT = json.loads((BENCH / "configs" / "stablelm-2-1.6b.json").read_text()
                    )["widest_gap_limit"]
 
 TINY_CONFIG = {
+    "block": "dense",
     "arch": "stablelm-1.6b",
     "changes": {"n_layers": 2, "d_model": 64, "n_heads": 4, "n_kv_heads": 2,
                 "head_dim": 16, "d_ff": 128, "vocab_size": 512,
@@ -64,11 +65,12 @@ def _run_module():
     return mod
 
 
-def _bench_dir(tmp_path, config=TINY_CONFIG, mixes=MIXES):
-    """A benchmark data directory that adds one config, two mixes and one
-    metric beside copies of the peak table and the metric files."""
+def _bench_dir(tmp_path, config=TINY_CONFIG, mixes=MIXES, blocks=None):
+    """A benchmark data directory that adds one config, two mixes, one
+    metric and the ``blocks`` (name: source) beside copies of the peak
+    table, the metric files and the blocks."""
     d = tmp_path / "bench"
-    for sub in ("configs", "traffic", "metrics"):
+    for sub in ("configs", "traffic", "metrics", "reference"):
         (d / sub).mkdir(parents=True)
     # The benchmark's peaks, and made-up ones for the CPU so that the
     # shares of a peak are read here too.
@@ -76,8 +78,11 @@ def _bench_dir(tmp_path, config=TINY_CONFIG, mixes=MIXES):
     peaks["cpu"] = {"bf16_flops_per_s": 1e12, "hbm_bytes_per_s": 1e11,
                     "source": "test"}
     (d / "peaks.json").write_text(json.dumps(peaks))
-    for f in (BENCH / "metrics").glob("*.py"):
-        shutil.copy(f, d / "metrics" / f.name)
+    for sub in ("metrics", "reference"):
+        for f in (BENCH / sub).glob("*.py"):
+            shutil.copy(f, d / sub / f.name)
+    for name, source in (blocks or {}).items():
+        (d / "reference" / f"{name}.py").write_text(source)
     (d / "configs" / "tiny-lm.json").write_text(json.dumps(config))
     for name, mix in mixes.items():
         (d / "traffic" / f"{name}.json").write_text(json.dumps(mix))
@@ -104,10 +109,10 @@ def _bench_dir(tmp_path, config=TINY_CONFIG, mixes=MIXES):
 
 
 def _execute(tmp_path, cell, trace=False, seconds=1.5, seed=2**35 + 1,
-             control=False, config=TINY_CONFIG, mixes=MIXES):
+             control=False, config=TINY_CONFIG, mixes=MIXES, blocks=None):
     from bench.lib import harness
 
-    d, bench = _bench_dir(tmp_path, config, mixes)
+    d, bench = _bench_dir(tmp_path, config, mixes, blocks)
     opts = harness.Options(out_dir=tmp_path / "out", trace_len_s=0.5,
                            trace_lead_s=0.2)
     return _run_module().execute(bench, cell, seed, seconds, trace,
@@ -136,6 +141,151 @@ def test_new_files_are_found_by_name(tmp_path, cell):
         cell == "tiny-open")
     assert list(res)[-1] == "compared"
     assert res["device"]["platform"] == "cpu"
+
+
+# A block added as a file: it names its sizes its own way (no
+# ``n_kv_heads``, ``head_dim`` or ``q_dim``) and computes the dense
+# block behind them, so the harness, the check and the count-reading
+# metrics find the model only through the block's functions.
+TOY_BLOCK = '''
+import dataclasses
+import functools
+
+from bench.reference import dense
+
+
+@dataclasses.dataclass(frozen=True)
+class ToySpec:
+    depth: int
+    width: int
+    heads: int
+    groups: int
+    hidden: int
+    vocab: int
+    dtype: str
+    raw: dict = dataclasses.field(default_factory=dict, compare=False)
+
+
+def load_spec(raw, name):
+    t = raw["toy"]
+    return ToySpec(t["depth"], t["width"], t["heads"], t["groups"],
+                   t["hidden"], t["tokens"], t["dtype"], raw)
+
+
+@functools.lru_cache(maxsize=None)
+def _dense(s):
+    return dense.Spec(
+        name="toy", n_layers=s.depth, d_model=s.width, n_heads=s.heads,
+        n_kv_heads=s.heads // s.groups, head_dim=s.width // s.heads,
+        d_ff=s.hidden, vocab=s.vocab, rope_theta=10000.0, norm_eps=1e-5,
+        dtype=s.dtype, raw=s.raw)
+
+
+def program_config(s):
+    return dense.program_config(_dense(s))
+
+
+def plain_weights(s, seed):
+    return dense.plain_weights(_dense(s), seed)
+
+
+def program_weights(s, seed, cfg):
+    return dense.program_weights(_dense(s), seed, cfg)
+
+
+def logits_at(w, s, tokens, rows, precision="fp32"):
+    return dense.logits_at(w, _dense(s), tokens, rows, precision)
+
+
+def prefill_flops(s, n):
+    return dense.prefill_flops(_dense(s), n)
+
+
+def decode_flops(s, n):
+    return dense.decode_flops(_dense(s), n)
+
+
+def prefill_bytes(s, n):
+    return dense.prefill_bytes(_dense(s), n)
+
+
+def decode_bytes(s, n):
+    return dense.decode_bytes(_dense(s), n)
+
+
+def weight_bytes(s):
+    return dense.weight_bytes(_dense(s))
+'''
+TOY_CONFIG = {
+    "block": "toy", "arch": TINY_CONFIG["arch"],
+    "changes": TINY_CONFIG["changes"], "widest_gap_limit": LIMIT,
+    "toy": {"depth": 2, "width": 64, "heads": 4, "groups": 2, "hidden": 128,
+            "tokens": 512, "dtype": "float32"},
+}
+
+
+@pytest.mark.parametrize("trace", [False, True], ids=["untraced", "traced"])
+@pytest.mark.parametrize("cell", sorted(MIXES))
+def test_a_block_is_new_files_only(tmp_path, cell, trace):
+    """A block and a config that names it, added as files: both tiny
+    cells run and are correct, and the count-reading metrics are read
+    through the block; no file of the benchmark changes."""
+    before = _repo_files()
+    res = _execute(tmp_path, cell, trace=trace, config=TOY_CONFIG,
+                   blocks={"toy": TOY_BLOCK})
+    assert _repo_files() == before
+    assert res["correct"] is True, res["compared"]
+    assert res["failed"] == 0 and res["attempted"] > 0
+    want = ({"model.mbu.decode"} if cell == "tiny-open"
+            else {"model.mfu", "model.mbu"}) if trace else set()
+    got = {m for m in res["metrics"] if m.startswith("model.m")}
+    assert got == want
+    assert all(res["metrics"][m]["value"] > 0 for m in got)
+
+
+def test_a_new_block_reads_the_control(tmp_path):
+    res = _execute(tmp_path, "tiny-closed", control=True, config=TOY_CONFIG,
+                   blocks={"toy": TOY_BLOCK})
+    assert res["check"]["control"] == "fp8"
+    assert res["compared"]["widest_gap"]["value"] == \
+        res["check"]["control_widest_gap"] >= 0.0
+    assert res["check"]["tokens"] > 0
+
+
+def test_a_config_that_names_no_block_is_refused(tmp_path):
+    from bench.lib import harness
+
+    config = {k: v for k, v in TINY_CONFIG.items() if k != "block"}
+    d, _ = _bench_dir(tmp_path, config)
+    with pytest.raises(ValueError, match='"block"'):
+        harness.load_config(d, "tiny-lm")
+
+
+DENSE_FIELDS = ("n_layers", "d_model", "n_heads", "n_kv_heads", "head_dim",
+                "d_ff", "q_dim", "kv_dim", "rope_theta", "norm_eps")
+
+
+def test_only_the_blocks_know_the_dense_layout():
+    """Outside ``bench/reference/`` (and the tests of the dense block)
+    no file of the benchmark imports ``dense``, ``counts`` or
+    ``weights``, or reads a field of the dense spec."""
+    import re
+
+    imports = re.compile(
+        r"^\s*(from\s+\S+\s+)?import\s.*\b(dense|counts|weights)\b",
+        re.M)
+    fields = re.compile(r"\.(%s)\b" % "|".join(DENSE_FIELDS))
+    files = [p for p in BENCH.rglob("*.py")
+             if p.relative_to(BENCH).parts[0] not in ("reference", "tests",
+                                                      "out")]
+    assert BENCH / "lib" / "harness.py" in files
+    bad = {str(p.relative_to(BENCH)): m.group(0)
+           for p in files for rx in (imports, fields)
+           for m in [rx.search(p.read_text())] if m}
+    assert bad == {}
+    assert not (BENCH / "lib" / "counts.py").exists()
+    assert not (BENCH / "lib" / "spec.py").exists()
+    assert not (BENCH / "lib" / "weights.py").exists()
 
 
 @pytest.mark.parametrize("cell", sorted(MIXES))

@@ -8,19 +8,24 @@ import jax
 import numpy as np
 import pytest
 
-from bench.lib import weights
-from bench.lib.spec import Spec
 from bench.reference import dense
+from bench.reference.common import served_rows, widest_gap
+from bench.tests.test_bench_harness import CHECK_CONFIG
 
 BENCH = pathlib.Path(__file__).resolve().parents[1]
 
 
-def spec_of(cfg) -> Spec:
-    return Spec(name=cfg.name, n_layers=cfg.n_layers, d_model=cfg.d_model,
-                n_heads=cfg.n_heads, n_kv_heads=cfg.n_kv_heads,
-                head_dim=cfg.resolved_head_dim, d_ff=cfg.d_ff,
-                vocab=cfg.vocab_size, rope_theta=cfg.rope_theta,
-                norm_eps=cfg.norm_eps, dtype=cfg.param_dtype)
+def spec_of(cfg) -> dense.Spec:
+    """The dense block's spec of a program config, read as a
+    configuration file under the published key names."""
+    raw = {"num_hidden_layers": cfg.n_layers, "hidden_size": cfg.d_model,
+           "num_attention_heads": cfg.n_heads,
+           "num_key_value_heads": cfg.n_kv_heads,
+           "head_dim": cfg.resolved_head_dim,
+           "intermediate_size": cfg.d_ff, "vocab_size": cfg.vocab_size,
+           "rope_theta": cfg.rope_theta, "rms_norm_eps": cfg.norm_eps,
+           "torch_dtype": cfg.param_dtype}
+    return dense.load_spec(raw, cfg.name)
 
 
 @pytest.mark.parametrize("arch", ["stablelm-1.6b", "h2o-danube-3-4b"])
@@ -33,26 +38,26 @@ def test_reference_matches_teacher_forced_logits(arch):
 
     cfg = get_reduced(arch)
     spec = spec_of(cfg)
-    params = weights.program_weights(spec, 3, cfg)
+    params = dense.program_weights(spec, 3, cfg)
     # Danube's reduced preset has a window of 32: stay inside it.
     prompt = np.random.default_rng(0).integers(0, cfg.vocab_size, 20)
     forced = [5, 77, 300, 9, 411]
     eng = ServeEngine(params, cfg, batch_size=1, max_len=32,
                       warmup_gemms=False)
     got = eng.teacher_forced_logits(prompt, forced)
-    w = weights.plain_weights(spec, 3)
+    w = dense.plain_weights(spec, 3)
     seq = list(prompt) + forced
     with jax.default_matmul_precision("highest"):
         want = dense.logits_at(w, spec, seq,
-                               dense.served_rows(len(prompt), 1 + len(forced)))
+                               served_rows(len(prompt), 1 + len(forced)))
     np.testing.assert_allclose(got, want, atol=1e-4 * np.abs(want).max())
-    assert dense.widest_gap(want, np.argmax(got, -1)) == 0.0
+    assert widest_gap(want, np.argmax(got, -1)) == 0.0
 
 
 def test_widest_gap():
     ref = np.array([[1.0, 3.0, 2.0], [0.5, 0.0, 0.25]])
-    assert dense.widest_gap(ref, [1, 0]) == 0.0
-    assert dense.widest_gap(ref, [2, 1]) == 1.0
+    assert widest_gap(ref, [1, 0]) == 0.0
+    assert widest_gap(ref, [2, 1]) == 1.0
 
 
 def test_fp8_control_is_not_correct():
@@ -61,14 +66,12 @@ def test_fp8_control_is_not_correct():
     limit = min(json.loads((BENCH / "configs" / f"{n}.json").read_text())
                 ["widest_gap_limit"]
                 for n in ("stablelm-2-1.6b", "h2o-danube3-4b"))
-    spec = Spec(name="control", n_layers=4, d_model=256, n_heads=4,
-                n_kv_heads=2, head_dim=64, d_ff=512, vocab=4096,
-                rope_theta=10000.0, norm_eps=1e-5, dtype="float32")
-    w = weights.plain_weights(spec, 11)
+    spec = dense.load_spec(CHECK_CONFIG, "control")
+    w = dense.plain_weights(spec, 11)
     toks = np.random.default_rng(1).integers(0, spec.vocab, 480)
     rows = np.arange(200, 480)
     with jax.default_matmul_precision("highest"):
         ref = dense.logits_at(w, spec, toks, rows)
         low = dense.logits_at(w, spec, toks, rows, precision="fp8")
-    assert dense.widest_gap(ref, np.argmax(ref, -1)) == 0.0
-    assert dense.widest_gap(ref, np.argmax(low, -1)) > limit
+    assert widest_gap(ref, np.argmax(ref, -1)) == 0.0
+    assert widest_gap(ref, np.argmax(low, -1)) > limit
